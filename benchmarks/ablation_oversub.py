@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core.greedy import greedy_allocate, static_allocate
 from repro.core.metrics import satisfaction_ratio
 from repro.core.nvpax import optimize
@@ -50,6 +51,7 @@ def run(factors=(0.95, 0.85, 0.75, 0.70), steps: int = 4) -> dict:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     import json
 
     print(json.dumps(run(), indent=1))

@@ -24,6 +24,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core.engine import AllocEngine
 from repro.core.nvpax import optimize
 from repro.core.problem import AllocProblem
@@ -137,6 +138,7 @@ def run(ns=(512, 2048), steps: int = 6, K: int = 8) -> dict:
 
 
 def main() -> None:
+    use_compile_cache()
     import argparse
     import json
     import os

@@ -32,6 +32,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core import engine as engine_mod
 from repro.core.engine import AllocEngine
 from repro.core.greedy import greedy_allocate
@@ -346,6 +347,7 @@ def run(geom, *, perf_steps: int = 5, brownout_steps: int = 8,
 
 
 def main() -> None:
+    use_compile_cache()
     import argparse
     import json
     import os

@@ -32,6 +32,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core.engine import AllocEngine, trace_count
 from repro.core.nvpax import NvpaxOptions
 from repro.core.solver import SolverOptions
@@ -252,6 +253,7 @@ def run(ns=(GATE_N,), steps: int = 60, seed: int = 0, fleet: bool = False) -> di
 
 
 def main() -> None:
+    use_compile_cache()
     import argparse
     import json
     import os

@@ -13,6 +13,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import use_compile_cache
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.pdhg_update import primal_update
@@ -80,6 +81,7 @@ def run() -> dict:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     import json
 
     print(json.dumps(run(), indent=1))

@@ -7,6 +7,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core.greedy import greedy_allocate
 from repro.core.metrics import satisfaction_ratio
 from repro.core.nvpax import optimize
@@ -32,6 +33,7 @@ def run() -> dict:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     import json
 
     print(json.dumps(run(), indent=1))

@@ -19,6 +19,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core import engine as engine_mod
 from repro.core.engine import AllocEngine
 from repro.obs import report as obs_report
@@ -96,6 +97,7 @@ def run(steps: int = 8, reps: int = 6, seed: int = 0) -> dict:
 
 
 def main() -> None:
+    use_compile_cache()
     import argparse
     import json
     import os

@@ -10,10 +10,15 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import sys
 import time
+import traceback
+
+from repro.compile_cache import use_compile_cache
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--out", default="artifacts/bench")
@@ -100,17 +105,18 @@ def main() -> None:
         ),
     ]
 
-    results = {}
+    failed = []
     for name, fn in suite:
         t0 = time.time()
         try:
             res = fn()
             status = "ok"
         except Exception as e:  # pragma: no cover
+            traceback.print_exc()
             res = {"error": f"{type(e).__name__}: {e}"}
             status = "ERROR"
+            failed.append(name)
         dt = time.time() - t0
-        results[name] = res
         with open(os.path.join(args.out, f"{name}.json"), "w") as f:
             json.dump(res, f, indent=1)
         line = f"[{status}] {name} ({dt:.1f}s)"
@@ -193,6 +199,9 @@ def main() -> None:
         elif status == "ERROR":
             line += "  " + res["error"]
         print(line, flush=True)
+    if failed:
+        # every phase still runs and writes its JSON, but the run fails
+        sys.exit(f"failed phases: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core.engine import AllocEngine
 from repro.core.greedy import greedy_allocate, static_allocate
 from repro.core.metrics import relative_improvement, satisfaction_ratio
@@ -108,6 +109,7 @@ def run(
 
 
 def main() -> None:
+    use_compile_cache()
     import argparse
     import json
     import os

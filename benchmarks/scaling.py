@@ -30,6 +30,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core.nvpax import optimize
 from repro.core.problem import AllocProblem
 from repro.pdn.hierarchy_gen import random_hierarchy
@@ -211,6 +212,7 @@ def run_bench(profile: str = "default"):
 
 
 def main() -> None:
+    use_compile_cache()
     import argparse
     import json
     import os

@@ -10,6 +10,7 @@ import numpy as np
 
 import jax.numpy as jnp
 
+from repro.compile_cache import use_compile_cache
 from repro.core.metrics import satisfaction_ratio, sla_margin, tenant_satisfaction
 from repro.core.nvpax import optimize
 from repro.core.problem import AllocProblem
@@ -62,6 +63,7 @@ def run(steps: int = 6, stride: int = 480, seed: int = 0) -> dict:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     import json
 
     print(json.dumps(run(), indent=1))

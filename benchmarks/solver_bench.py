@@ -14,6 +14,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core.batched import optimize_batched
 from repro.core.nvpax import NvpaxOptions, optimize
 from repro.core.problem import AllocProblem
@@ -126,9 +127,9 @@ def run_degenerate(n_seeds: int = 2) -> dict:
     restart counts, optimum quality vs HiGHS when scipy is present) and the
     full three-phase engine step is timed.
     """
+    import jax
     import jax.numpy as jnp
 
-    from repro.compat import enable_x64
     from repro.core import phases, solver
     from repro.core.engine import AllocEngine
     from repro.core.refsolve import HAVE_SCIPY, ref_solve
@@ -136,7 +137,7 @@ def run_degenerate(n_seeds: int = 2) -> dict:
     from repro.pdn.tree import build_from_level_sizes
 
     cases = []
-    with enable_x64(True):
+    with jax.enable_x64(True):
         for seed in range(n_seeds):
             for ties in (False, True):
                 pdn = build_from_level_sizes(
@@ -215,6 +216,7 @@ def run_degenerate(n_seeds: int = 2) -> dict:
 
 
 def main() -> None:
+    use_compile_cache()
     import argparse
     import json
     import os

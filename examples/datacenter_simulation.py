@@ -9,11 +9,13 @@ import argparse
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.pdn.tree import build_datacenter
 from repro.power.simulator import DatacenterSim
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--devices", type=int, default=None,

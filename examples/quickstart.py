@@ -9,6 +9,7 @@ both baselines.  Runs in a few seconds on CPU.
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core.greedy import greedy_allocate, static_allocate
 from repro.core.metrics import satisfaction_ratio
 from repro.core.nvpax import optimize
@@ -18,6 +19,7 @@ from repro.pdn.tree import build_from_level_sizes
 
 
 def main():
+    use_compile_cache()
     # 2 halls x 4 racks x 4 servers x 8 GPUs = 256 devices, oversub 0.85/level
     pdn = build_from_level_sizes([2, 4, 4], gpus_per_server=8)
     print(

@@ -12,6 +12,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import use_compile_cache
 from repro.configs import get_arch
 from repro.models import build
 from repro.pdn.tree import build_from_level_sizes
@@ -21,6 +22,7 @@ from repro.training.step import make_serve_steps
 
 
 def main():
+    use_compile_cache()
     cfg = get_arch("qwen3-4b").reduced()
     api = build(cfg)
     params, _ = api.init(jax.random.key(0))

@@ -18,6 +18,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import use_compile_cache
 from repro.configs import get_arch
 from repro.data.pipeline import SyntheticLMData
 from repro.models import build
@@ -48,6 +49,7 @@ def hundred_m_config():
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--batch", type=int, default=4)

@@ -49,7 +49,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from repro.compat import enable_x64
 from repro.core import phases, solver
 from repro.core.nvpax import NvpaxOptions
 from repro.core.problem import AllocProblem
@@ -861,7 +860,7 @@ def optimize_batched(
     Output matches per-scenario :func:`repro.core.nvpax.optimize` to solver
     tolerance (asserted in ``tests/test_batched.py``).
     """
-    ctx = enable_x64(True) if options.x64 else contextlib.nullcontext()
+    ctx = jax.enable_x64(True) if options.x64 else contextlib.nullcontext()
     t0 = time.perf_counter()
     with ctx:  # stack + solve under one x64 context (no silent f32 downcast)
         stacked = aps if isinstance(aps, AllocProblem) else stack_problems(aps)

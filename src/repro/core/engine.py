@@ -50,7 +50,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import enable_x64
 from repro.core import phases
 from repro.core.batched import (
     _PROBE_FULL_BUDGET,
@@ -244,7 +243,7 @@ class AllocEngine:
         self.history: list[dict[str, Any]] = []
 
     def _ctx(self):
-        return enable_x64(True) if self._x64 else contextlib.nullcontext()
+        return jax.enable_x64(True) if self._x64 else contextlib.nullcontext()
 
     @property
     def n(self) -> int:

@@ -15,10 +15,10 @@ import time
 from dataclasses import dataclass, field
 from typing import Any
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import enable_x64
 from repro.core import phases
 from repro.core import solver as solver_mod
 from repro.core.problem import AllocProblem
@@ -34,7 +34,7 @@ class NvpaxOptions:
     run_phase2: bool = True
     run_phase3: bool = True
     max_rounds: int = phases.MAX_ROUNDS
-    x64: bool = True  # solve in float64 (repro.compat.enable_x64 context)
+    x64: bool = True  # solve in float64 (jax.enable_x64 context)
     # exact water-filling fast path for the max-min phases on SLA-free
     # problems (beyond-paper optimization; equals the iterated-LP limit)
     use_waterfill: bool = True
@@ -94,7 +94,7 @@ def optimize(
     solve is skipped entirely (``stats["skipped"]``) or restarted after
     Phase I (``stats["certify_pass"]``) — see ``repro.core.solver.certify``.
     """
-    ctx = enable_x64(True) if options.x64 else contextlib.nullcontext()
+    ctx = jax.enable_x64(True) if options.x64 else contextlib.nullcontext()
     t0 = time.perf_counter()
 
     def in_budget() -> bool:

@@ -127,8 +127,7 @@ def repair(
         x = l + (x - l) * fac_dev
     # -- tree caps, one level at a time (ranges at equal depth are disjoint) --
     depths = ap.tree.depth
-    lcs = jnp.concatenate([jnp.zeros((1,), x.dtype), jnp.cumsum(l)])
-    lmin_node = lcs[ap.tree.end] - lcs[ap.tree.start]
+    lmin_node = tree_matvec(l, ap.tree)
 
     def scale_level(d, x):
         level = depths == d
@@ -138,11 +137,9 @@ def repair(
         fac_node = jnp.where(
             over, jnp.maximum(ap.tree.cap - lmin_node, 0.0) / denom, 1.0
         )
-        # broadcast factors onto (disjoint) ranges via a difference array
-        diff = jnp.zeros((x.shape[0] + 1,), x.dtype)
-        diff = diff.at[ap.tree.start].add(fac_node - 1.0)
-        diff = diff.at[ap.tree.end].add(-(fac_node - 1.0))
-        fac_dev = 1.0 + jnp.cumsum(diff)[: x.shape[0]]
+        # broadcast factors onto (disjoint) ranges: the adjoint's
+        # difference-array scatter + prefix sum
+        fac_dev = 1.0 + tree_rmatvec(fac_node - 1.0, ap.tree, x.shape[0])
         return l + (x - l) * fac_dev
 
     x = lax.fori_loop(0, n_depths, scale_level, x)

@@ -22,8 +22,10 @@ Two levels:
 
 from __future__ import annotations
 
+import contextlib
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -86,11 +88,9 @@ class FleetTopology(NamedTuple):
         normalized: bool = False,
         dtype=jnp.float64,
     ) -> "FleetTopology":
-        import contextlib
-
-        from repro.compat import enable_x64
-
-        ctx = enable_x64(True) if dtype == jnp.float64 else contextlib.nullcontext()
+        ctx = (
+            jax.enable_x64(True) if dtype == jnp.float64 else contextlib.nullcontext()
+        )
         with ctx:
             if sla is None:
                 sla = SlaTopo.empty(dtype)
@@ -211,11 +211,9 @@ class AllocProblem(NamedTuple):
             raise ValueError("priorities must be >= 1")
         # f64 conversion must happen under an x64 context or jax silently
         # truncates to f32.
-        import contextlib
-
-        from repro.compat import enable_x64  # local import keeps import light
-
-        ctx = enable_x64(True) if dtype == jnp.float64 else contextlib.nullcontext()
+        ctx = (
+            jax.enable_x64(True) if dtype == jnp.float64 else contextlib.nullcontext()
+        )
         with ctx:
             if topology is None:
                 topology = FleetTopology.from_pdn(

@@ -5,7 +5,7 @@ Phases II/III with HiGHS — CPU-only machinery built around sparse
 factorizations.  This package is the TPU-native replacement (DESIGN.md
 section 2): a Chambolle-Pock primal-dual iteration whose only
 non-elementwise work is the structured constraint matvec of
-:mod:`repro.core.treeops` (cumsum + gathers + segment sums), shared by every
+:mod:`repro.core.treeops` (prefix sums + gathers + segment sums), shared by every
 consumer — the host phase drivers (:mod:`repro.core.phases`), the
 vmapped batched engine (:mod:`repro.core.batched`), the persistent
 :class:`~repro.core.engine.AllocEngine`, and the fleet orchestrator's

@@ -98,16 +98,10 @@ def _matvecs(x, tree, sla, opts: SolverOptions | None):
     ``use_pallas_tree`` path (same routing as the solver loop)."""
     if opts is not None and opts.use_pallas_tree:
         from repro.kernels import tree_matvec as tk
-        from repro.kernels.pdhg_update import ops as _pk
 
-        interpret = (
-            _pk.default_interpret()
-            if opts.pallas_interpret is None
-            else opts.pallas_interpret
-        )
-        kx = tk.tree_matvec(x, tree.start, tree.end, interpret=interpret)
+        kx = tk.tree_matvec(x, tree.start, tree.end)
         sx = (
-            tk.sla_matvec(x, sla.dev, sla.ten, sla.k, interpret=interpret)
+            tk.sla_matvec(x, sla.dev, sla.ten, sla.k)
             if sla.k
             else treeops.sla_matvec(x, sla)
         )

@@ -97,16 +97,8 @@ def solve(
     neg_inf_tree = jnp.full((m,), -inf, dtype)
     pos_inf_imp = jnp.full((n,), inf, dtype)
 
-    if opts.use_pallas or opts.use_pallas_stats or opts.use_pallas_tree:
+    if opts.use_pallas or opts.use_pallas_stats:
         from repro.kernels.pdhg_update import ops as _pk
-
-        interpret = (
-            _pk.default_interpret()
-            if opts.pallas_interpret is None
-            else opts.pallas_interpret
-        )
-    else:
-        interpret = True
 
     # per-dual-block primal weights (PDLP multi-block style): the SLA rows
     # get their own omega, and tau_x is recomputed per iteration from the
@@ -144,13 +136,10 @@ def solve(
             sc,
             n,
             use_kernels=opts.use_pallas_tree,
-            interpret=interpret,
         )
         if opts.use_pallas:
             # fused primal prox + extrapolation, one HBM round-trip
-            x1, xe = _pk.primal_update(
-                x, gx, c_s, w_s, target_s, lo_s, hi_s, tau_x, interpret=interpret
-            )
+            x1, xe = _pk.primal_update(x, gx, c_s, w_s, target_s, lo_s, hi_s, tau_x)
         else:
             # primal prox (diagonal quadratic + box)
             x1 = jnp.clip(
@@ -170,15 +159,10 @@ def solve(
             sla,
             sc,
             use_kernels=opts.use_pallas_tree,
-            interpret=interpret,
         )
         if opts.use_pallas:
-            y_tree1 = _pk.dual_prox(
-                y_tree, a_tree, sig_tree, neg_inf_tree, tree_hi_s, interpret=interpret
-            )
-            y_imp1 = _pk.dual_prox(
-                y_imp, a_imp, sig_imp, imp_lo_s, pos_inf_imp, interpret=interpret
-            )
+            y_tree1 = _pk.dual_prox(y_tree, a_tree, sig_tree, neg_inf_tree, tree_hi_s)
+            y_imp1 = _pk.dual_prox(y_imp, a_imp, sig_imp, imp_lo_s, pos_inf_imp)
         else:
             y_tree1 = _dual_prox(
                 y_tree + sig_tree * a_tree, sig_tree, neg_inf_tree, tree_hi_s
@@ -312,13 +296,13 @@ def solve(
             # fused chunk-boundary bookkeeping: average accumulation + move
             # norms + restart-candidate travel, one streaming pass per block
             ax, move_num, move_den, dx2_cur, dx2_avg = _pk.primal_chunk_stats(
-                x, c.px, c.rx, c.ax, cnt, interpret=interpret
+                x, c.px, c.rx, c.ax, cnt
             )
             ayt, dyt2_cur, dyt2_avg, dyt2_zero = _pk.dual_chunk_stats(
-                yt, c.ry_tree, c.ayt, cnt, interpret=interpret
+                yt, c.ry_tree, c.ayt, cnt
             )
             ayi, dyi2_cur, dyi2_avg, dyi2_zero = _pk.dual_chunk_stats(
-                yi, c.ry_imp, c.ayi, cnt, interpret=interpret
+                yi, c.ry_imp, c.ayi, cnt
             )
             at_ = c.at + t
             ays = c.ays + ys

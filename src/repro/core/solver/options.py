@@ -47,10 +47,9 @@ class SolverOptions(NamedTuple):
     # n-sized primal/dual blocks of the inner iteration; the tiny SLA block
     # and the scalar t stay jnp.  Parity with the pure-jnp path is asserted
     # in tests/test_kernels.py.
+    # The kernels compile for Mosaic on a TPU backend and run the traced
+    # interpreter elsewhere (repro.kernels.resolve_interpret).
     use_pallas: bool = False
-    # None -> auto: interpret mode off only on TPU (the BlockSpecs are
-    # TPU-shaped; every other backend runs the traced interpreter).
-    pallas_interpret: bool | None = None
     # -- solver-core overhaul knobs (PR 5) ---------------------------------
     # Diagonal (Pock-Chambolle) step sizes computed in closed form from the
     # tree/SLA incidence; False falls back to scalar steps from the global
@@ -78,7 +77,7 @@ class SolverOptions(NamedTuple):
     # -- sharded-dispatch / Pallas-native knobs (PR 6) ---------------------
     # Route the tree prefix / SLA segment matvecs of the inner iteration
     # through the chunked Pallas kernels (repro.kernels.tree_matvec) instead
-    # of the plain jnp cumsum/segment_sum in repro.core.treeops.
+    # of the plain jnp prefix_sum/segment_sum in repro.core.treeops.
     use_pallas_tree: bool = False
     # Fuse the between-chunk restart/KKT bookkeeping (average accumulation,
     # no-progress move norms, restart-candidate travel distances) into

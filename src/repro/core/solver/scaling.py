@@ -120,8 +120,7 @@ def make_scales(prob: StepProblem, tree: TreeTopo, sla: SlaTopo) -> Scales:
     mov = (prob.hi - prob.lo > 0).astype(dtype)
     t_mov = (prob.t_hi - prob.t_lo > 0).astype(dtype)
     s2m = s * s * mov
-    csum = jnp.concatenate([jnp.zeros((1,), dtype), jnp.cumsum(s2m)])
-    tree_norm2 = csum[tree.end] - csum[tree.start]
+    tree_norm2 = tree_matvec(s2m, tree)
     d_tree = lax.rsqrt(jnp.maximum(tree_norm2, 1.0))
     if sla.k > 0:
         sla_norm2 = jax.ops.segment_sum(s2m[sla.dev], sla.ten, num_segments=sla.k)
@@ -132,7 +131,7 @@ def make_scales(prob: StepProblem, tree: TreeTopo, sla: SlaTopo) -> Scales:
     return Scales(s, s_t, mov, t_mov, d_tree, d_sla, d_imp)
 
 
-def scaled_matvec(xs, ts, tree, sla, sc: Scales, *, use_kernels=False, interpret=True):
+def scaled_matvec(xs, ts, tree, sla, sc: Scales, *, use_kernels=False):
     """Scaled forward operator D2 K_mov S, split by row block.  Input is the
     SCALED primal (x~, t~); pinned columns are zeroed (folded into bounds).
 
@@ -144,12 +143,8 @@ def scaled_matvec(xs, ts, tree, sla, sc: Scales, *, use_kernels=False, interpret
     if use_kernels:
         from repro.kernels import tree_matvec as tk
 
-        kx = tk.tree_matvec(x, tree.start, tree.end, interpret=interpret)
-        sx = (
-            tk.sla_matvec(x, sla.dev, sla.ten, sla.k, interpret=interpret)
-            if sla.k
-            else sla_matvec(x, sla)
-        )
+        kx = tk.tree_matvec(x, tree.start, tree.end)
+        sx = tk.sla_matvec(x, sla.dev, sla.ten, sla.k) if sla.k else sla_matvec(x, sla)
     else:
         kx = tree_matvec(x, tree)
         sx = sla_matvec(x, sla)
@@ -161,20 +156,16 @@ def scaled_matvec(xs, ts, tree, sla, sc: Scales, *, use_kernels=False, interpret
 
 
 def scaled_rmatvec(
-    y_tree, y_sla, y_imp, tree, sla, sc: Scales, n, *, use_kernels=False, interpret=True
+    y_tree, y_sla, y_imp, tree, sla, sc: Scales, n, *, use_kernels=False
 ):
     """Scaled adjoint S K_mov^T D2 -> (grad on x~, grad on t~)."""
     yi = sc.d_imp * y_imp
     if use_kernels:
         from repro.kernels import tree_matvec as tk
 
-        gx = tk.tree_rmatvec(
-            sc.d_tree * y_tree, tree.start, tree.end, n, interpret=interpret
-        )
+        gx = tk.tree_rmatvec(sc.d_tree * y_tree, tree.start, tree.end, n)
         if sla.k:
-            gx = gx + tk.sla_rmatvec(
-                sc.d_sla * y_sla, sla.dev, sla.ten, n, interpret=interpret
-            )
+            gx = gx + tk.sla_rmatvec(sc.d_sla * y_sla, sla.dev, sla.ten, n)
         gx = gx + yi
     else:
         gx = (
@@ -202,8 +193,7 @@ def pc_step_sizes(
     act = jnp.isfinite(prob.imp_lo).astype(dtype)  # improvement row is live
 
     # row absolute sums of A = D K_mov S
-    csum = jnp.concatenate([jnp.zeros((1,), dtype), jnp.cumsum(sm)])
-    row_tree = sc.d_tree * (csum[tree.end] - csum[tree.start])
+    row_tree = sc.d_tree * tree_matvec(sm, tree)
     if sla.k > 0:
         row_sla = sc.d_sla * jax.ops.segment_sum(
             sm[sla.dev], sla.ten, num_segments=sla.k

@@ -61,15 +61,18 @@ def waterfill_arrays(
         if not np.isfinite(t):
             break
         x = np.where(live, x + t, x)
-        # freeze: devices at u, or under any node now tight
+        # freeze: devices at u, or under any node now tight.  The nodes and
+        # devices whose rate set t are frozen by that comparison, not by the
+        # recomputed sums: the sums' rounding can exceed the 1e-9 tolerance,
+        # and a round that froze nothing would end the sweep early.
         xcs = np.concatenate([[0.0], np.cumsum(x)])
         sums = xcs[end] - xcs[start]
-        tight = (cap - sums <= 1e-9) & (n_live > 0)
+        tight = ((node_rate <= t) | (cap - sums <= 1e-9)) & (n_live > 0)
         under_tight = np.zeros(n + 1)
         np.add.at(under_tight, start[tight], 1.0)
         np.add.at(under_tight, end[tight], -1.0)
         under_tight = np.cumsum(under_tight)[:n] > 0
-        newly = live & ((u - x <= 1e-9) | under_tight)
+        newly = live & ((dev_rate <= t) | (u - x <= 1e-9) | under_tight)
         if not newly.any():
             break  # unbounded direction fully absorbed (all at u) or stalled
         live &= ~newly
@@ -111,10 +114,12 @@ def waterfill_jax(base, opt_mask, tree, u, max_rounds: int = 10_000):
         finite = jnp.isfinite(t)
         # numpy sweep breaks BEFORE applying a non-finite raise
         x_new = jnp.where(live & finite, x + t, x)
-        # freeze: devices at u, or under any node now tight
-        tight = (tree.cap - tree_matvec(x_new, tree) <= 1e-9) & (n_live > 0)
+        # freeze: devices at u, or under any node now tight (the rates that
+        # set t freeze exactly, as in the numpy sweep)
+        slack_new = tree.cap - tree_matvec(x_new, tree)
+        tight = ((node_rate <= t) | (slack_new <= 1e-9)) & (n_live > 0)
         under_tight = tree_rmatvec(tight.astype(dtype), tree, n) > 0.5
-        newly = live & ((u - x_new <= 1e-9) | under_tight)
+        newly = live & ((dev_rate <= t) | (u - x_new <= 1e-9) | under_tight)
         stalled = ~jnp.any(newly)  # unbounded direction absorbed or stalled
         done = (~finite) | stalled
         live_new = jnp.where(finite, live & ~newly, live)

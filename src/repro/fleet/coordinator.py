@@ -144,11 +144,10 @@ def split_entitlements(
         return np.zeros(0), np.zeros(0)
     if _SPLIT is None:
         _SPLIT = _entitlement_split_jit()
+    import jax
     import jax.numpy as jnp
 
-    from repro.compat import enable_x64
-
-    with enable_x64(True):
+    with jax.enable_x64(True):
         lo, hi = _SPLIT(
             jnp.asarray(slice_floor, jnp.float64),
             jnp.asarray(slice_umax, jnp.float64),

@@ -58,7 +58,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import enable_x64
 from repro.core import phases, solver
 from repro.core.batched import BatchMeta, solve_three_phase
 from repro.core.engine import AllocEngine, _shape_requests
@@ -457,7 +456,7 @@ class FleetOrchestrator:
     # -- stacked-mode array management -------------------------------------
 
     def _ctx(self):
-        return enable_x64(True) if self._x64 else contextlib.nullcontext()
+        return jax.enable_x64(True) if self._x64 else contextlib.nullcontext()
 
     def _upload(self) -> None:
         """(Re)build the padded [K, ...] device arrays from host mirrors."""

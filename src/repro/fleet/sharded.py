@@ -47,7 +47,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.core.treeops import TreeTopo
 from repro.core.waterfill import waterfill_jax
 
@@ -227,9 +226,12 @@ def _step_jit(
         rec_cfg=rec_cfg,
     )
     sharded, rep_spec = P(_AXIS), P()
-    fn = compat.shard_map(
+    # the replication checker is off: the coordinator outputs are replicated
+    # by construction (computed from one psum), which it cannot verify
+    fn = jax.shard_map(
         body,
-        mesh,
+        mesh=mesh,
+        check_vma=False,
         in_specs=(
             sharded,
             sharded,

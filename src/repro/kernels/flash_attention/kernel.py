@@ -25,6 +25,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 __all__ = ["flash_attention"]
 
 NEG_INF = -1e30
@@ -75,7 +77,7 @@ def _fa_kernel(
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "bq", "bk", "interpret"))
-def flash_attention(q, k, v, *, causal=True, bq=512, bk=512, interpret=True):
+def flash_attention(q, k, v, *, causal=True, bq=512, bk=512, interpret=None):
     """q: [B,Sq,H,dh]; k,v: [B,Sk,KV,dh] -> [B,Sq,H,dh].
 
     GQA is handled by folding the head-group repeat into the index map (no
@@ -124,6 +126,6 @@ def flash_attention(q, k, v, *, causal=True, bq=512, bk=512, interpret=True):
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, dh), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qf, kf, vf)
     return out.reshape(B, H, Sq, dh).transpose(0, 2, 1, 3)

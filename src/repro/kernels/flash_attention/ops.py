@@ -1,4 +1,5 @@
-"""Jitted public wrapper for the flash-attention kernel (interpret on CPU)."""
+"""Jitted public wrapper for the flash-attention kernel (interpreted off the
+TPU; see :func:`repro.kernels.resolve_interpret`)."""
 
 from __future__ import annotations
 

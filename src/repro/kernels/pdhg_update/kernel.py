@@ -24,7 +24,7 @@ norms, sum for the squared travel), combined across the tiny ``[n_blocks]``
 axis by the caller.
 
 Validated in interpret mode against ``ref.py`` (CPU has no Pallas TPU
-lowering); on real TPU hardware drop ``interpret=True``.
+lowering); ``interpret=None`` compiles for Mosaic on a TPU backend.
 """
 
 from __future__ import annotations
@@ -34,6 +34,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import resolve_interpret
 
 __all__ = [
     "primal_update",
@@ -75,7 +77,7 @@ def _as_vec(v, n, dtype):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block"))
-def primal_update(x, gx, c, w, target, lo, hi, tau, *, interpret=True, block=BLOCK):
+def primal_update(x, gx, c, w, target, lo, hi, tau, *, interpret=None, block=BLOCK):
     n = x.shape[0]
     np_ = pl.cdiv(n, block) * block
     args = [_pad(v, np_) for v in (x, gx, c, w, target, lo, hi)]
@@ -92,13 +94,13 @@ def primal_update(x, gx, c, w, target, lo, hi, tau, *, interpret=True, block=BLO
             jax.ShapeDtypeStruct((np_,), x.dtype),
             jax.ShapeDtypeStruct((np_,), x.dtype),
         ),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*args)
     return x1[:n], xe[:n]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block"))
-def dual_prox(y, a, sigma, lo, hi, *, interpret=True, block=BLOCK):
+def dual_prox(y, a, sigma, lo, hi, *, interpret=None, block=BLOCK):
     n = y.shape[0]
     np_ = pl.cdiv(n, block) * block
     big = jnp.asarray(jnp.finfo(y.dtype).max / 2, y.dtype)
@@ -116,7 +118,7 @@ def dual_prox(y, a, sigma, lo, hi, *, interpret=True, block=BLOCK):
         in_specs=[spec] * 5,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((np_,), y.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*args)
     return out[:n]
 
@@ -151,7 +153,7 @@ def _dual_stats_kernel(y_ref, ry_ref, ay_ref, cnt_ref, ayn_ref, part_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block"))
-def primal_chunk_stats(x, px, rx, ax, cnt, *, interpret=True, block=BLOCK):
+def primal_chunk_stats(x, px, rx, ax, cnt, *, interpret=None, block=BLOCK):
     """One fused pass over the primal block at a KKT check.
 
     Returns ``(ax + x, max|x - px|, max|x|, sum (x - rx)^2,
@@ -175,7 +177,7 @@ def primal_chunk_stats(x, px, rx, ax, cnt, *, interpret=True, block=BLOCK):
             jax.ShapeDtypeStruct((np_,), x.dtype),
             jax.ShapeDtypeStruct((nb, 4), x.dtype),
         ),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*args)
     return (
         axn[:n],
@@ -187,7 +189,7 @@ def primal_chunk_stats(x, px, rx, ax, cnt, *, interpret=True, block=BLOCK):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block"))
-def dual_chunk_stats(y, ry, ay, cnt, *, interpret=True, block=BLOCK):
+def dual_chunk_stats(y, ry, ay, cnt, *, interpret=None, block=BLOCK):
     """Dual-side twin of :func:`primal_chunk_stats`.
 
     Returns ``(ay + y, sum (y - ry)^2, sum (ay_new/cnt - ry)^2,
@@ -209,6 +211,6 @@ def dual_chunk_stats(y, ry, ay, cnt, *, interpret=True, block=BLOCK):
             jax.ShapeDtypeStruct((np_,), y.dtype),
             jax.ShapeDtypeStruct((nb, 3), y.dtype),
         ),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*args)
     return ayn[:n], jnp.sum(part[:, 0]), jnp.sum(part[:, 1]), jnp.sum(part[:, 2])

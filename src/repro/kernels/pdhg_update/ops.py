@@ -1,15 +1,11 @@
 """Jitted public wrappers for the fused PDHG update kernel.
 
-``interpret`` defaults to True because this container has no TPU; the
-launcher flips it off on real hardware (the BlockSpecs are TPU-shaped).
-:func:`default_interpret` is the backend-aware switch used by
-``repro.core.pdhg.solve`` when ``SolverOptions.use_pallas`` is set with
-``pallas_interpret=None``.
+``interpret`` defaults to None: the kernel compiles for Mosaic on a TPU
+backend and runs the traced interpreter elsewhere
+(:func:`repro.kernels.resolve_interpret`).
 """
 
 from __future__ import annotations
-
-import jax
 
 from repro.kernels.pdhg_update.kernel import (
     dual_chunk_stats,
@@ -23,10 +19,4 @@ __all__ = [
     "dual_prox",
     "primal_chunk_stats",
     "dual_chunk_stats",
-    "default_interpret",
 ]
-
-
-def default_interpret() -> bool:
-    """Real Pallas lowering only on TPU; the traced interpreter elsewhere."""
-    return jax.default_backend() != "tpu"

@@ -22,7 +22,7 @@ scale (n = 1e5-1e6+) that busts the 16 MB budget, so everything here is
   is dropped on return.
 
 Validated in interpret mode against ``ref.py`` (CPU has no Pallas TPU
-lowering); on real TPU hardware drop ``interpret=True``.
+lowering); ``interpret=None`` compiles for Mosaic on a TPU backend.
 """
 
 from __future__ import annotations
@@ -32,6 +32,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import resolve_interpret
 
 __all__ = ["tree_matvec", "tree_rmatvec", "sla_matvec", "sla_rmatvec", "BLOCK"]
 
@@ -68,7 +70,7 @@ def _blocked_prefix(x, *, interpret, block):
             jax.ShapeDtypeStruct((np_,), x.dtype),
             jax.ShapeDtypeStruct((nb,), x.dtype),
         ),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(_pad_to(x, np_))
     off = jnp.concatenate([jnp.zeros((1,), x.dtype), jnp.cumsum(tot)])[:nb]
     return pl.pallas_call(
@@ -77,7 +79,7 @@ def _blocked_prefix(x, *, interpret, block):
         in_specs=[spec, pl.BlockSpec((nb,), lambda i: (0,))],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((np_,), x.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(local, off)
 
 
@@ -102,7 +104,7 @@ def _scatter_diff_kernel(y_ref, start_ref, end_ref, diff_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block", "row_block"))
-def tree_matvec(x, start, end, *, interpret=True, block=BLOCK, row_block=BLOCK):
+def tree_matvec(x, start, end, *, interpret=None, block=BLOCK, row_block=BLOCK):
     """out[j] = sum x[start_j:end_j], chunked over devices and rows.
 
     Padded rows use the empty range [n, n) so they contribute exact zeros.
@@ -119,13 +121,13 @@ def tree_matvec(x, start, end, *, interpret=True, block=BLOCK, row_block=BLOCK):
         in_specs=[pl.BlockSpec((n,), lambda i: (0,)), rspec, rspec],
         out_specs=rspec,
         out_shape=jax.ShapeDtypeStruct((mp,), x.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(csum, _pad_to(start, mp, value=n), _pad_to(end, mp, value=n))
     return out[:m]
 
 
 @functools.partial(jax.jit, static_argnames=("n", "interpret", "block", "row_block"))
-def tree_rmatvec(y, start, end, n, *, interpret=True, block=BLOCK, row_block=BLOCK):
+def tree_rmatvec(y, start, end, n, *, interpret=None, block=BLOCK, row_block=BLOCK):
     """Adjoint via blocked difference-array scatter + blocked prefix sum."""
     m = y.shape[0]
     mp = pl.cdiv(m, row_block) * row_block
@@ -137,7 +139,7 @@ def tree_rmatvec(y, start, end, n, *, interpret=True, block=BLOCK, row_block=BLO
         in_specs=[rspec, rspec, rspec],
         out_specs=pl.BlockSpec((n + 1,), lambda i: (0,)),
         out_shape=jax.ShapeDtypeStruct((n + 1,), y.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(_pad_to(y, mp), _pad_to(start, mp), _pad_to(end, mp))
     return _blocked_prefix(diff, interpret=interpret, block=block)[:n]
 
@@ -163,7 +165,7 @@ def _sla_rmatvec_kernel(y_ref, dev_ref, ten_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret", "edge_block"))
-def sla_matvec(x, dev, ten, k, *, interpret=True, edge_block=BLOCK):
+def sla_matvec(x, dev, ten, k, *, interpret=None, edge_block=BLOCK):
     """Per-tenant sums over the incidence edge list, chunked over edges:
     out[t] = sum_{e: ten_e = t} x[dev_e]."""
     e = dev.shape[0]
@@ -178,13 +180,13 @@ def sla_matvec(x, dev, ten, k, *, interpret=True, edge_block=BLOCK):
         in_specs=[pl.BlockSpec((x.shape[0],), lambda i: (0,)), espec, espec],
         out_specs=pl.BlockSpec((k + 1,), lambda i: (0,)),
         out_shape=jax.ShapeDtypeStruct((k + 1,), x.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, _pad_to(dev, ep), _pad_to(ten, ep, value=k))
     return out[:k]
 
 
 @functools.partial(jax.jit, static_argnames=("n", "interpret", "edge_block"))
-def sla_rmatvec(y, dev, ten, n, *, interpret=True, edge_block=BLOCK):
+def sla_rmatvec(y, dev, ten, n, *, interpret=None, edge_block=BLOCK):
     """Adjoint: device d accumulates its tenants' duals, chunked over edges.
     Padded edges read an inert zero dual and scatter to an inert slot."""
     e = dev.shape[0]
@@ -201,6 +203,6 @@ def sla_rmatvec(y, dev, ten, n, *, interpret=True, edge_block=BLOCK):
         in_specs=[pl.BlockSpec((k + 1,), lambda i: (0,)), espec, espec],
         out_specs=pl.BlockSpec((n + 1,), lambda i: (0,)),
         out_shape=jax.ShapeDtypeStruct((n + 1,), y.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(y_ext, _pad_to(dev, ep, value=n), _pad_to(ten, ep, value=k))
     return out[:n]

@@ -1,5 +1,5 @@
-"""Jitted public wrappers for the tree/segment matvec kernels
-(interpret=True on CPU)."""
+"""Jitted public wrappers for the tree/segment matvec kernels (interpreted
+off the TPU; see :func:`repro.kernels.resolve_interpret`)."""
 
 from __future__ import annotations
 

@@ -33,14 +33,13 @@ class TenantLayout:
 
     def sla_topo(self, dtype=None) -> SlaTopo:
         """Incidence-list SlaTopo for the solver."""
+        import jax
         import jax.numpy as jnp
-
-        from repro.compat import enable_x64
 
         dtype = dtype or jnp.float64
         dev = np.nonzero(self.tenant_of >= 0)[0].astype(np.int32)
         ten = self.tenant_of[dev].astype(np.int32)
-        with enable_x64(dtype == jnp.float64):
+        with jax.enable_x64(dtype == jnp.float64):
             return SlaTopo(
                 dev=jnp.asarray(dev),
                 ten=jnp.asarray(ten),

@@ -14,9 +14,9 @@ import pytest
 @pytest.fixture(scope="session")
 def x64():
     """Enable float64 for the duration of a test (context-managed)."""
-    from repro.compat import enable_x64
+    import jax
 
-    with enable_x64(True):
+    with jax.enable_x64(True):
         yield
 
 

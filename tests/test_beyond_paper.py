@@ -10,8 +10,6 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.compat import enable_x64
-
 from repro.core import pdhg, phases
 from repro.core.nvpax import NvpaxOptions, optimize
 from repro.core.problem import AllocProblem
@@ -60,7 +58,7 @@ def test_vmap_over_scenarios(pdn):
     """The jitted solver vmaps over request scenarios (MPC what-if): one
     compiled program evaluates K candidate futures; results match
     per-scenario solves."""
-    with enable_x64(True):
+    with jax.enable_x64(True):
         rng = np.random.default_rng(3)
         K = 3
         reqs = rng.uniform(150, 650, (K, pdn.n))

@@ -9,8 +9,6 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.compat import enable_x64
-
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.pdhg_update import (
@@ -51,7 +49,7 @@ from repro.pdn.tree import build_from_level_sizes
 def test_primal_update_sweep(n, dtype, vector_tau):
     """Scalar steps (uniform fallback) and per-variable step vectors (the
     preconditioned form the solver core streams) both match the oracle."""
-    with enable_x64(dtype == jnp.float64):
+    with jax.enable_x64(dtype == jnp.float64):
         rng = np.random.default_rng(n)
 
         def mk():
@@ -76,7 +74,7 @@ def test_primal_update_sweep(n, dtype, vector_tau):
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.float64])
 @pytest.mark.parametrize("vector_sigma", [False, True])
 def test_dual_prox_sweep(n, dtype, vector_sigma):
-    with enable_x64(dtype == jnp.float64):
+    with jax.enable_x64(dtype == jnp.float64):
         rng = np.random.default_rng(n + 1)
 
         def mk():
@@ -120,7 +118,7 @@ def test_pdhg_solve_pallas_parity():
 @pytest.mark.parametrize("sizes", [[2, 2], [3, 2, 2], [4, 4]])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.float64])
 def test_tree_matvec_sweep(sizes, dtype):
-    with enable_x64(dtype == jnp.float64):
+    with jax.enable_x64(dtype == jnp.float64):
         pdn = build_from_level_sizes(sizes, gpus_per_server=4)
         rng = np.random.default_rng(0)
         x = jnp.asarray(rng.normal(size=pdn.n), dtype)

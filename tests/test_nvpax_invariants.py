@@ -14,9 +14,8 @@ pytest.importorskip("hypothesis")
 from hypothesis import given, settings  # noqa: E402
 from hypothesis import strategies as st  # noqa: E402
 
+import jax
 import jax.numpy as jnp
-
-from repro.compat import enable_x64
 
 from repro.core.metrics import satisfaction_ratio
 from repro.core.nvpax import optimize
@@ -181,7 +180,7 @@ def test_sla_lower_bound_forces_idle_up():
     pdn = build_from_level_sizes([2, 2], gpus_per_server=4)  # 16 devices
     from repro.core.treeops import SlaTopo
 
-    with enable_x64(True):
+    with jax.enable_x64(True):
         sla = SlaTopo(
             dev=jnp.arange(4, dtype=jnp.int32),
             ten=jnp.zeros(4, jnp.int32),

@@ -11,7 +11,8 @@ import jax.numpy as jnp
 from repro.core import pdhg, phases
 from repro.core.nvpax import NvpaxOptions, optimize
 from repro.core.problem import AllocProblem
-from repro.core.waterfill import waterfill
+from repro.core.treeops import TreeTopo
+from repro.core.waterfill import waterfill, waterfill_arrays, waterfill_jax
 from repro.pdn.tree import build_from_level_sizes
 
 pytestmark = pytest.mark.usefixtures("x64")
@@ -86,6 +87,35 @@ def test_waterfill_respects_frozen_devices(small_pdn):
     x = waterfill(small_pdn, base, mask)
     np.testing.assert_array_equal(x[::2], base[::2])
     assert (x[1::2] > base[1::2]).any()
+
+
+@pytest.mark.parametrize("impl", ["numpy", "jax"])
+def test_waterfill_freezes_binding_node_despite_rounding(impl):
+    """The node whose rate set the raise freezes even when its recomputed
+    subtree sum rounds more than 1e-9 W below its cap.  At 1e8 W one ulp is
+    1.5e-8 W; float64 as emulated on a TPU rounds that coarsely at hall
+    scale.  A round that froze nothing used to end the sweep early, here
+    with node B's devices left near their base instead of filled to 500 W."""
+    # root [0, 4) over A = [0, 2) and B = [2, 4); A binds first
+    start, end = np.array([0, 0, 2]), np.array([4, 2, 4])
+    base = np.array([50000000.22715759, 50000000.62318715, 100.0, 100.0])
+    cap_a = 100000001.69049819
+    cap = np.array([cap_a + 1000.0, cap_a, 1e9])
+    u = np.full(4, 1e9)
+    opt = np.ones(4, bool)
+    if impl == "numpy":
+        x = waterfill_arrays(start, end, cap, u, base, opt)
+    else:
+        tree = TreeTopo(
+            jnp.asarray(start, jnp.int32),
+            jnp.asarray(end, jnp.int32),
+            jnp.asarray(cap),
+            jnp.asarray([0, 1, 1], jnp.int32),
+        )
+        x = np.asarray(
+            waterfill_jax(jnp.asarray(base), jnp.asarray(opt), tree, jnp.asarray(u))
+        )
+    np.testing.assert_allclose(x[2:], 500.0, atol=1e-6)
 
 
 def test_phase1_processes_priorities_high_to_low():

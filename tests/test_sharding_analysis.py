@@ -17,8 +17,8 @@ from repro.analysis.flops import analyze_hlo
 from repro.analysis.hlo import collective_stats, shape_bytes
 from repro.sharding.logical import default_rules, resolve_spec
 
-MESH = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
-POD = AbstractMesh((("data", 16), ("model", 16)))
+MESH = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
+POD = AbstractMesh((16, 16), ("data", "model"))
 
 
 def rules(mesh=MESH, **kw):

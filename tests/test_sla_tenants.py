@@ -6,9 +6,8 @@ from __future__ import annotations
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
-
-from repro.compat import enable_x64
 
 from repro.core.metrics import (
     relative_improvement,
@@ -87,7 +86,7 @@ def test_metrics_formulas():
 def test_max_only_sla_cap_enforced(setup):
     """A tenant max budget caps its aggregate below unconstrained level."""
     pdn, lay, sim = setup
-    with enable_x64(True):
+    with jax.enable_x64(True):
         from repro.core.treeops import SlaTopo
 
         dev = jnp.arange(8, dtype=jnp.int32)
