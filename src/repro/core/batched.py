@@ -107,6 +107,7 @@ class BatchedStepState(NamedTuple):
     kkt_res: jnp.ndarray  # dtype scalar
     restarts: jnp.ndarray  # int32
     kkt_hist: jnp.ndarray  # [KKT_HIST_BUCKETS] int32
+    waterfill_rounds: jnp.ndarray  # int32: rounds of the max-min waterfill
 
 
 @dataclass
@@ -217,6 +218,7 @@ def _phase1_scan(
         kkt_res=jnp.zeros((), ap.l.dtype),
         restarts=jnp.zeros((), jnp.int32),
         kkt_hist=jnp.zeros((KKT_HIST_BUCKETS,), jnp.int32),
+        waterfill_rounds=jnp.zeros((), jnp.int32),
     )
     if not meta.levels:
         return init
@@ -248,6 +250,7 @@ def _phase1_scan(
                 kkt_res=jnp.maximum(st.kkt_res, res),
                 restarts=st.restarts + stats.restarts,
                 kkt_hist=st.kkt_hist + stats.score_hist,
+                waterfill_rounds=st.waterfill_rounds,
             )
 
         # the host driver only sweeps levels present among this scenario's
@@ -292,7 +295,7 @@ def _maxmin_loop(
     """
     dtype = ap.l.dtype
     if meta.use_waterfill and ap.sla.k == 0:
-        x_wf = waterfill_jax(x, opt_set, ap.tree, ap.u)
+        x_wf, rounds = waterfill_jax(x, opt_set, ap.tree, ap.u)
         return BatchedStepState(
             x=x_wf,
             solver=warm,
@@ -305,6 +308,7 @@ def _maxmin_loop(
             kkt_res=jnp.zeros((), dtype),
             restarts=jnp.zeros((), jnp.int32),
             kkt_hist=jnp.zeros((KKT_HIST_BUCKETS,), jnp.int32),
+            waterfill_rounds=rounds,
         )
 
     # freeze devices with no slack at entry (see phases.run_maxmin_phase)
@@ -321,6 +325,7 @@ def _maxmin_loop(
         kkt_res=jnp.zeros((), dtype),
         restarts=jnp.zeros((), jnp.int32),
         kkt_hist=jnp.zeros((KKT_HIST_BUCKETS,), jnp.int32),
+        waterfill_rounds=jnp.zeros((), jnp.int32),
     )
 
     def cond(st: BatchedStepState):
@@ -367,6 +372,7 @@ def _maxmin_loop(
             kkt_res=jnp.maximum(st.kkt_res, res),
             restarts=st.restarts + stats.restarts,
             kkt_hist=st.kkt_hist + stats.score_hist,
+            waterfill_rounds=st.waterfill_rounds,
         )
 
     return lax.while_loop(cond, body, init)
@@ -428,7 +434,8 @@ def solve_three_phase(
     else:
         skip = skip_any = None
 
-    p1 = _phase1_scan(ap, meta, opts, w1, skip=skip_any)
+    with jax.named_scope("phase1"):
+        p1 = _phase1_scan(ap, meta, opts, w1, skip=skip_any)
     if carry is not None:
         # substitute the carried Phase I point (both tiers reuse it)
         carried_sol = solver.SolverState(carry.x1, w1.t, w1.y_tree, w1.y_sla, w1.y_imp)
@@ -454,6 +461,7 @@ def solve_three_phase(
             kkt_res=jnp.zeros((), dtype),
             restarts=jnp.zeros((), jnp.int32),
             kkt_hist=jnp.zeros((KKT_HIST_BUCKETS,), jnp.int32),
+            waterfill_rounds=jnp.zeros((), jnp.int32),
         )
 
     def refine(x, sol, opt_set, free_set, iters_before):
@@ -481,7 +489,8 @@ def solve_three_phase(
 
     w2 = phases.merge_warm(p1.solver, warm.p2 if warm is not None else None)
     if meta.run_phase2:
-        p2, cut2 = refine(x1, w2, ap.active, ap.idle, p1.iterations)
+        with jax.named_scope("phase2"):
+            p2, cut2 = refine(x1, w2, ap.active, ap.idle, p1.iterations)
         if carry is not None:
             p2 = p2._replace(x=jnp.where(skip, dec.x_snap, p2.x))
         x2 = p2.x
@@ -494,14 +503,16 @@ def solve_three_phase(
                          certified=jnp.asarray(True),
                          kkt_res=jnp.zeros((), dtype),
                          restarts=jnp.zeros((), jnp.int32),
-                         kkt_hist=jnp.zeros((KKT_HIST_BUCKETS,), jnp.int32))
+                         kkt_hist=jnp.zeros((KKT_HIST_BUCKETS,), jnp.int32),
+                         waterfill_rounds=jnp.zeros((), jnp.int32))
         x2 = x1
 
     w3 = phases.merge_warm(p2.solver, warm.p3 if warm is not None else None)
     if meta.run_phase3:
         empty = jnp.zeros_like(ap.active)
-        p3, cut3 = refine(x2, w3, ap.idle, empty,
-                          p1.iterations + p2.iterations)
+        with jax.named_scope("phase3"):
+            p3, cut3 = refine(x2, w3, ap.idle, empty,
+                              p1.iterations + p2.iterations)
         if carry is not None:
             p3 = p3._replace(x=jnp.where(skip, dec.x_snap, p3.x))
         x3 = p3.x
@@ -514,7 +525,8 @@ def solve_three_phase(
                          certified=jnp.asarray(True),
                          kkt_res=jnp.zeros((), dtype),
                          restarts=jnp.zeros((), jnp.int32),
-                         kkt_hist=jnp.zeros((KKT_HIST_BUCKETS,), jnp.int32))
+                         kkt_hist=jnp.zeros((KKT_HIST_BUCKETS,), jnp.int32),
+                         waterfill_rounds=jnp.zeros((), jnp.int32))
         x3 = x2
 
     stats = {
@@ -526,6 +538,10 @@ def solve_three_phase(
         "iterations_p1": p1.iterations,
         "iterations_p2": p2.iterations,
         "iterations_p3": p3.iterations,
+        # rounds of the max-min waterfill (the SLA-free Phase II/III path,
+        # which runs no PDHG iteration)
+        "waterfill_rounds_p2": p2.waterfill_rounds,
+        "waterfill_rounds_p3": p3.waterfill_rounds,
         "converged": p1.converged & p2.converged & p3.converged,
         "kkt_certified": p1.certified & p2.certified & p3.certified,
         "truncated": truncated,
@@ -672,6 +688,8 @@ def _solve_batched(
             "iterations_p1": zi,
             "iterations_p2": zi,
             "iterations_p3": zi,
+            "waterfill_rounds_p2": zi,
+            "waterfill_rounds_p3": zi,
             "converged": yes,
             "kkt_certified": yes,
             "truncated": jnp.zeros((kk,), bool),

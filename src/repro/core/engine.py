@@ -64,6 +64,7 @@ from repro.core.problem import AllocProblem, FleetTopology
 from repro.core.solver import certify
 from repro.core.treeops import SlaTopo
 from repro.obs import recorder as obs_recorder
+from repro.obs import spans
 from repro.obs.stats import StepStats
 from repro.pdn.tree import FlatPDN, check_caps_fund_minimums
 
@@ -489,61 +490,88 @@ class AllocEngine:
         Zero rebuild work: the only host-side cost is the O(n) request
         pre-processing and the telemetry/active transfer; everything else is
         one compiled program, warm-started from the previous step.
+
+        ``wall_time_s`` of the result is the whole step on the host clock,
+        pre-processing through the stats fetch.  With
+        :mod:`repro.obs.spans` enabled the step records ``engine.step``
+        holding ``engine.prepare`` (pre-processing, deadline budget,
+        recorder init), ``engine.upload`` (telemetry and active mask to the
+        device), ``engine.dispatch`` (the compiled program's call),
+        ``engine.wait`` (until the caps are computed), ``engine.fetch``
+        (the three allocations to host numpy) and ``engine.stats``.
         """
-        req, act = self._preprocess(telemetry, active)
-        budget = self._budget(deadline_s)
-        t0 = time.perf_counter()
-        with self._ctx():
-            # None (cold) and carry (steady) are two jit variants; the cold
-            # one must stay warm=None so its phase chaining is bit-identical
-            # to the host driver's cold path.  The incremental anchor is a
-            # third traced input: skip/solve transitions share one program.
-            inc = self._inc_carry if self.options.incremental else None
-            if self._rec_cfg is not None and self._rec_state is None:
-                self._rec_state = obs_recorder.init_state(
-                    self._rec_cfg, self.n, self.dtype
+        with spans.span("engine.step"):
+            t0 = time.perf_counter()
+            with spans.span("engine.prepare"):
+                req, act = self._preprocess(telemetry, active)
+                budget = self._budget(deadline_s)
+                # the incremental anchor is a traced input: skip/solve
+                # transitions share one program
+                inc = self._inc_carry if self.options.incremental else None
+                if self._rec_cfg is not None and self._rec_state is None:
+                    with self._ctx():
+                        self._rec_state = obs_recorder.init_state(
+                            self._rec_cfg, self.n, self.dtype
+                        )
+            with self._ctx():
+                with spans.span("engine.upload"):
+                    r_dev = jnp.asarray(req, self.dtype)
+                    act_dev = jnp.asarray(act)
+                    budget_dev = (
+                        None if budget is None else jnp.asarray(budget, jnp.int32)
+                    )
+                # None (cold) and carry (steady) are two jit variants; the
+                # cold one must stay warm=None so its phase chaining is
+                # bit-identical to the host driver's cold path.
+                with spans.span("engine.dispatch"):
+                    x1, x2, x3, solver, stats, new_carry, new_rec = _engine_step_jit(
+                        self.fleet,
+                        r_dev,
+                        self.priority,
+                        act_dev,
+                        self._warm,
+                        budget_dev,
+                        inc,
+                        self._rec_state,
+                        meta=self.meta,
+                        opts=self.options.solver,
+                        rec_cfg=self._rec_cfg,
+                    )
+                with spans.span("engine.wait"):
+                    x3 = x3.block_until_ready()
+            self._warm = solver
+            if self.options.incremental:
+                self._inc_carry = new_carry
+            if self._rec_cfg is not None:
+                self._rec_state = new_rec
+            with spans.span("engine.fetch"):
+                allocation, phase1, phase2 = (
+                    np.asarray(x3), np.asarray(x1), np.asarray(x2)
                 )
-            x1, x2, x3, solver, stats, new_carry, new_rec = _engine_step_jit(
-                self.fleet,
-                jnp.asarray(req, self.dtype),
-                self.priority,
-                jnp.asarray(act),
-                self._warm,
-                None if budget is None else jnp.asarray(budget, jnp.int32),
-                inc,
-                self._rec_state,
-                meta=self.meta,
-                opts=self.options.solver,
-                rec_cfg=self._rec_cfg,
-            )
-            x3 = x3.block_until_ready()
-        wall = time.perf_counter() - t0
-        self._warm = solver
-        if self.options.incremental:
-            self._inc_carry = new_carry
-        if self._rec_cfg is not None:
-            self._rec_state = new_rec
-        res = AllocResult(
-            allocation=np.asarray(x3),
-            phase1=np.asarray(x1),
-            phase2=np.asarray(x2),
+            with spans.span("engine.stats"):
+                step_stats = StepStats.from_jit(stats, scalar=True, iter_budget=budget)
+                wall = time.perf_counter() - t0
+                self.history.append(
+                    {
+                        "wall_s": wall,
+                        "converged": step_stats["converged"],
+                        "solves": step_stats["total_solves"],
+                        "iterations": step_stats["total_iterations"],
+                        "phase_iterations": step_stats["phase_iterations"],
+                        "waterfill_rounds": step_stats["waterfill_rounds"],
+                        "truncated": step_stats["truncated"],
+                        "skipped": step_stats["skipped"],
+                    }
+                )
+        return AllocResult(
+            allocation=allocation,
+            phase1=phase1,
+            phase2=phase2,
             warm_state=solver,
             wall_time_s=wall,
             carry=new_carry if self.options.incremental else None,
-            stats=StepStats.from_jit(stats, scalar=True, iter_budget=budget),
+            stats=step_stats,
         )
-        self.history.append(
-            {
-                "wall_s": wall,
-                "converged": res.stats["converged"],
-                "solves": res.stats["total_solves"],
-                "iterations": res.stats["total_iterations"],
-                "phase_iterations": res.stats["phase_iterations"],
-                "truncated": res.stats["truncated"],
-                "skipped": res.stats["skipped"],
-            }
-        )
-        return res
 
     # -- batched control step ----------------------------------------------
 

@@ -11,6 +11,7 @@ from __future__ import annotations
 
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
@@ -93,6 +94,7 @@ def merge_warm(
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("repair")
 def repair(
     x: jnp.ndarray, ap: AllocProblem, n_depths: int | None = None
 ) -> jnp.ndarray:

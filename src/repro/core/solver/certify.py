@@ -111,6 +111,7 @@ def _matvecs(x, tree, sla, opts: SolverOptions | None):
     return kx, sx
 
 
+@jax.named_scope("certify")
 def certify_step(
     ap: AllocProblem,
     carry: IncrementalCarry,

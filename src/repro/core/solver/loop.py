@@ -47,6 +47,7 @@ def _dual_prox(z, sigma, lo, hi):
 
 
 @functools.partial(jax.jit, static_argnames=("opts",))
+@jax.named_scope("pdhg")
 def solve(
     prob: StepProblem,
     tree: TreeTopo,

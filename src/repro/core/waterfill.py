@@ -87,7 +87,12 @@ def waterfill_jax(base, opt_mask, tree, u, max_rounds: int = 10_000):
     ``tree`` is a :class:`repro.core.treeops.TreeTopo`; semantics and
     freezing order mirror the numpy sweep exactly (cross-validated in
     tests), so host and jitted paths produce the same allocation.
+
+    Returns ``(x, rounds)``: the allocation and the sweep's int32 round
+    count, which the loop carries anyway.  The sweep runs under the
+    ``waterfill`` named scope, so a device profile can attribute its ops.
     """
+    import jax
     import jax.numpy as jnp
     from jax import lax
 
@@ -125,10 +130,11 @@ def waterfill_jax(base, opt_mask, tree, u, max_rounds: int = 10_000):
         live_new = jnp.where(finite, live & ~newly, live)
         return x_new, live_new, done, rounds + 1
 
-    x, _, _, _ = lax.while_loop(
-        cond, body, (x0, live0, jnp.asarray(False), jnp.asarray(0, jnp.int32))
-    )
-    return x
+    with jax.named_scope("waterfill"):
+        x, _, _, rounds = lax.while_loop(
+            cond, body, (x0, live0, jnp.asarray(False), jnp.asarray(0, jnp.int32))
+        )
+    return x, rounds
 
 
 def waterfill(

@@ -107,11 +107,11 @@ def _entitlement_split_jit():
         # minimum split: demand-free max-min raise of the slice floors until
         # each tenant row reaches b_min (stable across steps, so churn
         # validation agrees with the next plan exactly)
-        lo = waterfill_jax(floor, mask, forest_min, umax)
+        lo, _ = waterfill_jax(floor, mask, forest_min, umax)
         # maximum split: demand-shaped first (hot slices get budget), then
         # headroom so the sub-budgets always sum to min(b_max, sum(umax))
-        hi = waterfill_jax(lo, mask, forest_max, jnp.clip(demand, lo, umax))
-        hi = waterfill_jax(hi, mask, forest_max, umax)
+        hi, _ = waterfill_jax(lo, mask, forest_max, jnp.clip(demand, lo, umax))
+        hi, _ = waterfill_jax(hi, mask, forest_max, umax)
         return lo, hi
 
     return split

@@ -246,6 +246,8 @@ def _solve_domains(
             "iterations_p1": zi,
             "iterations_p2": zi,
             "iterations_p3": zi,
+            "waterfill_rounds_p2": zi,
+            "waterfill_rounds_p3": zi,
             "converged": yes,
             "kkt_certified": yes,
             "truncated": jnp.zeros((kk,), bool),
@@ -1361,7 +1363,7 @@ class FleetOrchestrator:
             else np.ones(self.k, bool)
         )
         allocs, solves, iters, phase_iters, conv = [], [], [], [], []
-        skipped, certify = [], []
+        skipped, certify, wf_rounds = [], [], []
         certified, truncated, kkt_res, restarts, kkt_hist = [], [], [], [], []
         for k, eng in enumerate(self._engines):
             rk = req[offs[k] : offs[k + 1]]
@@ -1382,6 +1384,7 @@ class FleetOrchestrator:
                 solves.append(0)
                 iters.append(0)
                 phase_iters.append([0, 0, 0])
+                wf_rounds.append([0, 0])
                 conv.append(True)
                 skipped.append(True)
                 certify.append(True)
@@ -1400,6 +1403,7 @@ class FleetOrchestrator:
             solves.append(res.stats["total_solves"])
             iters.append(res.stats["total_iterations"])
             phase_iters.append(res.stats["phase_iterations"])
+            wf_rounds.append(res.stats["waterfill_rounds"])
             conv.append(res.stats["converged"])
             skipped.append(bool(res.stats.get("skipped", False)))
             certify.append(bool(res.stats.get("certify_pass", False)))
@@ -1436,6 +1440,7 @@ class FleetOrchestrator:
             kkt_res=np.asarray(kkt_res),
             restarts=np.asarray(restarts),
             kkt_hist=np.stack(kkt_hist, axis=0),
+            waterfill_rounds=np.asarray(wf_rounds),
             mode="loop",
         )
         return np.concatenate(allocs), stats

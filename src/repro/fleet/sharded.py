@@ -159,10 +159,10 @@ def _sharded_solve(
     mask_k = jnp.ones((k_total,), bool)
     grants = rep.dmin_tot
     if coord_mode == "waterfill":
-        grants = waterfill_jax(
+        grants, _ = waterfill_jax(
             grants, mask_k, ctree, jnp.clip(demand, rep.dmin_tot, rep.dcap)
         )
-    grants = waterfill_jax(grants, mask_k, ctree, rep.dcap)
+    grants, _ = waterfill_jax(grants, mask_k, ctree, rep.dcap)
 
     if S:
         slice_demand = agg[k_total:]
@@ -173,13 +173,13 @@ def _sharded_solve(
             depth=jnp.zeros(rep.b_max_c.shape[0], jnp.int32),
         )
         mask_s = jnp.ones((S,), bool)
-        slice_hi = waterfill_jax(
+        slice_hi, _ = waterfill_jax(
             rep.slice_lo,
             mask_s,
             forest,
             jnp.clip(slice_demand, rep.slice_lo, rep.slice_umax),
         )
-        slice_hi = waterfill_jax(slice_hi, mask_s, forest, rep.slice_umax)
+        slice_hi, _ = waterfill_jax(slice_hi, mask_s, forest, rep.slice_umax)
         lo_ext = jnp.concatenate([rep.slice_lo, jnp.zeros((1,), dt)])
         hi_ext = jnp.concatenate([slice_hi, jnp.full((1,), jnp.inf, dt)])
         sla_lo = jnp.maximum(rowmap.lo_local, lo_ext[rowmap.slice_idx])

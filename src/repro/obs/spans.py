@@ -4,7 +4,7 @@ The in-jit recorder (:mod:`repro.obs.recorder`) sees everything the compiled
 step program does, but a control interval also spends wall time in host code:
 telemetry decode, coordinator planning, dispatch bookkeeping, result fetch.
 Spans cover that half — nestable, thread-local, near-free when disabled
-(one attribute check per call site).
+(one attribute check per call site, no annotation opened).
 
 Usage::
 
@@ -20,10 +20,11 @@ Span names nest by the runtime stack: a ``span("solve")`` opened inside
 ``span("fleet.step")`` records as ``fleet.step/solve``, so the summary
 shows where each parent's time actually went.
 
-Perfetto: :func:`span` also emits a ``jax.profiler.TraceAnnotation`` when
-tracing has been switched on via :func:`profile_trace` (or an external
-``jax.profiler.start_trace``), so host stages line up with device ops in
-the trace viewer.
+Profiler: an enabled :func:`span` also opens a
+``jax.profiler.TraceAnnotation`` of its path, so inside a profiler session
+(:func:`profile_trace`, or an outside ``jax.profiler.start_trace``) the host
+stages land on the profiler's own clock beside the device operations; outside
+a session the annotation records nothing.
 """
 
 from __future__ import annotations
@@ -34,6 +35,7 @@ import threading
 import time
 from typing import Any, Callable, Iterator
 
+import jax
 import numpy as np
 
 __all__ = [
@@ -53,20 +55,18 @@ _records: list[tuple[str, float, float]] = []  # (path, t0, duration_s)
 _local = threading.local()
 
 _enabled = False
-_annotate = False  # also emit jax.profiler.TraceAnnotation per span
+_OFF = contextlib.nullcontext()
 
 
-def enable(*, annotate: bool = False) -> None:
-    """Turn span recording on (optionally with profiler annotations)."""
-    global _enabled, _annotate
+def enable() -> None:
+    """Turn span recording (and the spans' profiler annotations) on."""
+    global _enabled
     _enabled = True
-    _annotate = annotate
 
 
 def disable() -> None:
-    global _enabled, _annotate
+    global _enabled
     _enabled = False
-    _annotate = False
 
 
 def enabled() -> bool:
@@ -80,28 +80,24 @@ def _stack() -> list[str]:
     return stack
 
 
-@contextlib.contextmanager
-def span(name: str) -> Iterator[None]:
+def span(name: str) -> contextlib.AbstractContextManager:
     """Record a named wall-clock span (no-op unless :func:`enable` ran)."""
     if not _enabled:
-        yield
-        return
+        return _OFF
+    return _record(name)
+
+
+@contextlib.contextmanager
+def _record(name: str) -> Iterator[None]:
     stack = _stack()
     path = "/".join(stack + [name]) if stack else name
     stack.append(name)
-    ann = None
-    if _annotate:
-        import jax
-
-        ann = jax.profiler.TraceAnnotation(path)
-        ann.__enter__()
     t0 = time.perf_counter()
     try:
-        yield
+        with jax.profiler.TraceAnnotation(path):
+            yield
     finally:
         dur = time.perf_counter() - t0
-        if ann is not None:
-            ann.__exit__(None, None, None)
         stack.pop()
         with _lock:
             _records.append((path, t0, dur))
@@ -158,16 +154,14 @@ def summary(records: list[dict[str, Any]] | None = None) -> dict[str, dict]:
 @contextlib.contextmanager
 def profile_trace(log_dir: str) -> Iterator[None]:
     """Opt-in Perfetto capture: wraps ``jax.profiler.start_trace`` and turns
-    on span annotations, so host stages appear alongside device ops in the
-    dumped trace (load it at ui.perfetto.dev)."""
-    global _enabled, _annotate
-    import jax
-
-    was_enabled, was_annotate = _enabled, _annotate
-    enable(annotate=True)
+    spans on, so host stages appear alongside device ops in the dumped trace
+    (load it at ui.perfetto.dev)."""
+    global _enabled
+    was_enabled = _enabled
+    enable()
     jax.profiler.start_trace(log_dir)
     try:
         yield
     finally:
         jax.profiler.stop_trace()
-        _enabled, _annotate = was_enabled, was_annotate
+        _enabled = was_enabled

@@ -38,9 +38,11 @@ class StepStats(dict):
     Canonical fields: ``solves``, ``iterations``, ``phase_iterations``
     (``[3]`` or ``[K, 3]``), ``converged``, ``skipped``, ``certify_pass``,
     and (when the producing path reports them) ``kkt_certified``,
-    ``truncated``, ``kkt_res``, ``restarts``, ``kkt_hist``.  Values are
-    Python scalars on the engine path and numpy arrays on batched/fleet
-    paths — the record is shape-agnostic on purpose.
+    ``truncated``, ``kkt_res``, ``restarts``, ``kkt_hist`` and
+    ``waterfill_rounds`` (``[2]`` or ``[K, 2]``: rounds of the max-min
+    waterfill in Phases II and III, the SLA-free path that runs no PDHG
+    iteration).  Values are Python scalars on the engine path and numpy
+    arrays on batched/fleet paths — the record is shape-agnostic on purpose.
     """
 
     @classmethod
@@ -58,6 +60,7 @@ class StepStats(dict):
         kkt_res: Any = None,
         restarts: Any = None,
         kkt_hist: Any = None,
+        waterfill_rounds: Any = None,
         **extras: Any,
     ) -> "StepStats":
         out = cls()
@@ -73,6 +76,7 @@ class StepStats(dict):
             "kkt_res": kkt_res,
             "restarts": restarts,
             "kkt_hist": kkt_hist,
+            "waterfill_rounds": waterfill_rounds,
         }
         for name, value in fields.items():
             if value is None:
@@ -90,7 +94,8 @@ class StepStats(dict):
     ) -> "StepStats":
         """Convert the traced stats dict of
         :func:`repro.core.batched.solve_three_phase` (keys ``solves``,
-        ``iterations``, ``iterations_p1..3``, flags) to host values.
+        ``iterations``, ``iterations_p1..3``, ``waterfill_rounds_p2..3``,
+        flags) to host values.
 
         ``scalar=True`` is the engine (K=1) path: leaves become Python
         ``int``/``bool``/``float`` scalars, matching the pre-PR-8 engine
@@ -98,6 +103,9 @@ class StepStats(dict):
         """
         pi = np.stack(
             [np.asarray(stats[f"iterations_p{i}"]) for i in (1, 2, 3)], axis=-1
+        )
+        wr = np.stack(
+            [np.asarray(stats[f"waterfill_rounds_p{i}"]) for i in (2, 3)], axis=-1
         )
         if scalar:
             return cls.build(
@@ -112,6 +120,7 @@ class StepStats(dict):
                 kkt_res=float(stats["kkt_res"]),
                 restarts=int(stats["restarts"]),
                 kkt_hist=np.asarray(stats["kkt_hist"]),
+                waterfill_rounds=[int(v) for v in wr],
                 **extras,
             )
         return cls.build(
@@ -126,6 +135,7 @@ class StepStats(dict):
             kkt_res=np.asarray(stats["kkt_res"]),
             restarts=np.asarray(stats["restarts"]),
             kkt_hist=np.asarray(stats["kkt_hist"]),
+            waterfill_rounds=wr,
             **extras,
         )
 

@@ -299,6 +299,103 @@ def test_orchestrator_emits_stage_spans(small_pdn):
     assert any(p.startswith("fleet.plan/coordinator.") for p in paths)
 
 
+ENGINE_SPANS = [
+    "engine.step/engine.prepare",
+    "engine.step/engine.upload",
+    "engine.step/engine.dispatch",
+    "engine.step/engine.wait",
+    "engine.step/engine.fetch",
+    "engine.step/engine.stats",
+]
+
+
+def test_engine_step_spans_nest_in_order_and_cover_the_wall(small_pdn):
+    eng = AllocEngine(small_pdn)
+    p = _powers(small_pdn.n, 1, seed=29)[0]
+    eng.step(p)  # compile the cold and the warm-carry programs
+    eng.step(p)
+    spans.reset()
+    spans.enable()
+    try:
+        res = eng.step(p)
+        recs = spans.drain()
+    finally:
+        spans.disable()
+    # a span is recorded when it closes: children in order, then the step
+    assert [r["span"] for r in recs] == ENGINE_SPANS + ["engine.step"]
+    outer = recs[-1]
+    t_end = outer["t0"] + outer["ms"] / 1e3
+    for r in recs[:-1]:
+        assert outer["t0"] <= r["t0"] <= r["t0"] + r["ms"] / 1e3 <= t_end
+    for a, b in zip(recs[:-2], recs[1:-1]):
+        assert a["t0"] + a["ms"] / 1e3 <= b["t0"]
+    # wall_time_s covers the whole step, from engine.prepare into engine.stats
+    assert recs[-2]["t0"] - recs[0]["t0"] <= res.wall_time_s <= outer["ms"] / 1e3
+    assert eng.history[-1]["wall_s"] == res.wall_time_s
+
+
+def test_disabled_spans_record_nothing_and_open_no_annotation(small_pdn, monkeypatch):
+    opened = []
+
+    class Annotation:
+        def __init__(self, name):
+            opened.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(spans.jax.profiler, "TraceAnnotation", Annotation)
+    eng = AllocEngine(small_pdn)
+    p = _powers(small_pdn.n, 1, seed=31)[0]
+    spans.reset()
+    eng.step(p)
+    assert spans.drain() == [] and opened == []
+    spans.enable()
+    try:
+        eng.step(p)
+    finally:
+        spans.disable()
+    assert opened == ["engine.step"] + ENGINE_SPANS
+    assert len(spans.drain()) == len(opened)
+
+
+def test_engine_spans_share_the_profiler_host_line(small_pdn, tmp_path):
+    """Inside a profiler session the enabled spans land as annotations on
+    the host thread that opened the caller's own span around the step."""
+    import jax
+    from jax.profiler import ProfileData
+
+    eng = AllocEngine(small_pdn)
+    p = _powers(small_pdn.n, 1, seed=37)[0]
+    eng.step(p)
+    spans.enable()
+    try:
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with jax.profiler.TraceAnnotation("interval"):
+                eng.step(p)
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        spans.disable()
+        spans.reset()
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    lines = [
+        {e.name: (e.start_ns, e.start_ns + e.duration_ns) for e in ln.events}
+        for plane in ProfileData.from_file(str(path)).planes
+        if plane.name == "/host:CPU"
+        for ln in plane.lines
+    ]
+    (line,) = [ev for ev in lines if "interval" in ev]
+    a, b = line["interval"]
+    for name in ["engine.step"] + ENGINE_SPANS:
+        s, e = line[name]
+        assert a <= s <= e <= b
+
+
 # -- StepStats consolidation ----------------------------------------------
 
 

@@ -113,7 +113,7 @@ def test_waterfill_freezes_binding_node_despite_rounding(impl):
             jnp.asarray([0, 1, 1], jnp.int32),
         )
         x = np.asarray(
-            waterfill_jax(jnp.asarray(base), jnp.asarray(opt), tree, jnp.asarray(u))
+            waterfill_jax(jnp.asarray(base), jnp.asarray(opt), tree, jnp.asarray(u))[0]
         )
     np.testing.assert_allclose(x[2:], 500.0, atol=1e-6)
 
@@ -139,3 +139,51 @@ def test_maxmin_phase_invariant_opt_plus_fixed():
     )
     assert st2.converged
     assert (np.asarray(x2) >= np.asarray(x1) - 1e-9).all()
+
+
+def _two_rack_tree():
+    """Root [0, 4) over rack A = [0, 2) (cap 100 W) and rack B = [2, 4) (cap
+    500 W); device 2 tops out at 150 W."""
+    start, end = np.array([0, 0, 2]), np.array([4, 2, 4])
+    cap = np.array([1e9, 100.0, 500.0])
+    u = np.array([1e9, 1e9, 150.0, 1e9])
+    return start, end, cap, u
+
+
+def test_waterfill_jax_counts_one_round_per_binding_event():
+    """From zero: A binds at 50 W each (round 1), device 2 reaches its u at
+    150 W (round 2), B binds with device 3 at 350 W (round 3); nothing is
+    left live, so the sweep stops after three rounds."""
+    start, end, cap, u = _two_rack_tree()
+    tree = TreeTopo(
+        jnp.asarray(start, jnp.int32),
+        jnp.asarray(end, jnp.int32),
+        jnp.asarray(cap),
+        jnp.asarray([0, 1, 1], jnp.int32),
+    )
+    x, rounds = waterfill_jax(
+        jnp.zeros(4), jnp.ones(4, bool), tree, jnp.asarray(u)
+    )
+    assert int(rounds) == 3
+    np.testing.assert_allclose(np.asarray(x), [50.0, 50.0, 150.0, 350.0])
+    np.testing.assert_allclose(
+        np.asarray(x), waterfill_arrays(start, end, cap, u, np.zeros(4), np.ones(4, bool))
+    )
+
+
+def test_engine_reports_the_waterfill_rounds_of_phases_2_and_3(small_pdn):
+    """``stats["waterfill_rounds"]`` is what a direct sweep from each phase's
+    start counts: Phase II raises the active devices from the Phase I caps,
+    Phase III the idle ones from the Phase II caps."""
+    from repro.core.engine import AllocEngine
+
+    eng = AllocEngine(small_pdn)
+    tele = np.random.default_rng(5).uniform(50.0, 800.0, small_pdn.n)
+    res = eng.step(tele)
+    active = jnp.asarray(tele >= eng.idle_threshold)
+    tree, u = eng.fleet.tree, eng.fleet.u
+    _, r2 = waterfill_jax(jnp.asarray(res.phase1), active, tree, u)
+    _, r3 = waterfill_jax(jnp.asarray(res.phase2), ~active, tree, u)
+    assert res.stats["waterfill_rounds"] == [int(r2), int(r3)]
+    assert int(r2) > 0
+    assert eng.history[-1]["waterfill_rounds"] == res.stats["waterfill_rounds"]
