@@ -22,8 +22,8 @@ This module re-expresses the same algorithm as a fixed-shape jax program:
   evaluated as traced predicates;
 * the exact feasibility repair is the shared fixed-trip
   ``phases.repair(..., n_depths)`` fori-loop;
-* the SLA-free max-min fast path is the trace-safe water-filling sweep
-  :func:`repro.core.waterfill.waterfill_jax`.
+* the SLA-free max-min fast path is the trace-safe level-wise tree
+  projection :func:`repro.core.waterfill.waterfill_project_jax`.
 
 Because every step-problem builder (``qp_step``, ``lp_step``,
 ``saturated_mask``, ``repair``) is imported from :mod:`repro.core.phases`,
@@ -53,7 +53,7 @@ from repro.core import phases, solver
 from repro.core.nvpax import NvpaxOptions
 from repro.core.problem import AllocProblem
 from repro.core.solver.options import KKT_HIST_BUCKETS
-from repro.core.waterfill import waterfill_jax
+from repro.core.waterfill import waterfill_project_jax
 from repro.obs import recorder as obs_recorder
 from repro.obs.stats import StepStats
 
@@ -107,7 +107,8 @@ class BatchedStepState(NamedTuple):
     kkt_res: jnp.ndarray  # dtype scalar
     restarts: jnp.ndarray  # int32
     kkt_hist: jnp.ndarray  # [KKT_HIST_BUCKETS] int32
-    waterfill_rounds: jnp.ndarray  # int32: rounds of the max-min waterfill
+    waterfill_rounds: jnp.ndarray  # int32: search steps of the max-min fill
+    waterfill_levels: jnp.ndarray  # int32: tree levels whose search ran
 
 
 @dataclass
@@ -219,6 +220,7 @@ def _phase1_scan(
         restarts=jnp.zeros((), jnp.int32),
         kkt_hist=jnp.zeros((KKT_HIST_BUCKETS,), jnp.int32),
         waterfill_rounds=jnp.zeros((), jnp.int32),
+        waterfill_levels=jnp.zeros((), jnp.int32),
     )
     if not meta.levels:
         return init
@@ -251,6 +253,7 @@ def _phase1_scan(
                 restarts=st.restarts + stats.restarts,
                 kkt_hist=st.kkt_hist + stats.score_hist,
                 waterfill_rounds=st.waterfill_rounds,
+                waterfill_levels=st.waterfill_levels,
             )
 
         # the host driver only sweeps levels present among this scenario's
@@ -295,7 +298,9 @@ def _maxmin_loop(
     """
     dtype = ap.l.dtype
     if meta.use_waterfill and ap.sla.k == 0:
-        x_wf, rounds = waterfill_jax(x, opt_set, ap.tree, ap.u)
+        x_wf, steps, levels = waterfill_project_jax(
+            x, opt_set, ap.tree, ap.u, meta.n_depths
+        )
         return BatchedStepState(
             x=x_wf,
             solver=warm,
@@ -308,7 +313,8 @@ def _maxmin_loop(
             kkt_res=jnp.zeros((), dtype),
             restarts=jnp.zeros((), jnp.int32),
             kkt_hist=jnp.zeros((KKT_HIST_BUCKETS,), jnp.int32),
-            waterfill_rounds=rounds,
+            waterfill_rounds=steps,
+            waterfill_levels=levels,
         )
 
     # freeze devices with no slack at entry (see phases.run_maxmin_phase)
@@ -326,6 +332,7 @@ def _maxmin_loop(
         restarts=jnp.zeros((), jnp.int32),
         kkt_hist=jnp.zeros((KKT_HIST_BUCKETS,), jnp.int32),
         waterfill_rounds=jnp.zeros((), jnp.int32),
+        waterfill_levels=jnp.zeros((), jnp.int32),
     )
 
     def cond(st: BatchedStepState):
@@ -373,6 +380,7 @@ def _maxmin_loop(
             restarts=st.restarts + stats.restarts,
             kkt_hist=st.kkt_hist + stats.score_hist,
             waterfill_rounds=st.waterfill_rounds,
+            waterfill_levels=st.waterfill_levels,
         )
 
     return lax.while_loop(cond, body, init)
@@ -462,6 +470,7 @@ def solve_three_phase(
             restarts=jnp.zeros((), jnp.int32),
             kkt_hist=jnp.zeros((KKT_HIST_BUCKETS,), jnp.int32),
             waterfill_rounds=jnp.zeros((), jnp.int32),
+            waterfill_levels=jnp.zeros((), jnp.int32),
         )
 
     def refine(x, sol, opt_set, free_set, iters_before):
@@ -504,7 +513,8 @@ def solve_three_phase(
                          kkt_res=jnp.zeros((), dtype),
                          restarts=jnp.zeros((), jnp.int32),
                          kkt_hist=jnp.zeros((KKT_HIST_BUCKETS,), jnp.int32),
-                         waterfill_rounds=jnp.zeros((), jnp.int32))
+                         waterfill_rounds=jnp.zeros((), jnp.int32),
+                         waterfill_levels=jnp.zeros((), jnp.int32))
         x2 = x1
 
     w3 = phases.merge_warm(p2.solver, warm.p3 if warm is not None else None)
@@ -526,7 +536,8 @@ def solve_three_phase(
                          kkt_res=jnp.zeros((), dtype),
                          restarts=jnp.zeros((), jnp.int32),
                          kkt_hist=jnp.zeros((KKT_HIST_BUCKETS,), jnp.int32),
-                         waterfill_rounds=jnp.zeros((), jnp.int32))
+                         waterfill_rounds=jnp.zeros((), jnp.int32),
+                         waterfill_levels=jnp.zeros((), jnp.int32))
         x3 = x2
 
     stats = {
@@ -538,10 +549,12 @@ def solve_three_phase(
         "iterations_p1": p1.iterations,
         "iterations_p2": p2.iterations,
         "iterations_p3": p3.iterations,
-        # rounds of the max-min waterfill (the SLA-free Phase II/III path,
-        # which runs no PDHG iteration)
+        # search steps of the max-min fill and the tree levels whose search
+        # ran (the SLA-free Phase II/III path, which runs no PDHG iteration)
         "waterfill_rounds_p2": p2.waterfill_rounds,
         "waterfill_rounds_p3": p3.waterfill_rounds,
+        "waterfill_levels_p2": p2.waterfill_levels,
+        "waterfill_levels_p3": p3.waterfill_levels,
         "converged": p1.converged & p2.converged & p3.converged,
         "kkt_certified": p1.certified & p2.certified & p3.certified,
         "truncated": truncated,
@@ -690,6 +703,8 @@ def _solve_batched(
             "iterations_p3": zi,
             "waterfill_rounds_p2": zi,
             "waterfill_rounds_p3": zi,
+            "waterfill_levels_p2": zi,
+            "waterfill_levels_p3": zi,
             "converged": yes,
             "kkt_certified": yes,
             "truncated": jnp.zeros((kk,), bool),

@@ -559,6 +559,7 @@ class AllocEngine:
                         "iterations": step_stats["total_iterations"],
                         "phase_iterations": step_stats["phase_iterations"],
                         "waterfill_rounds": step_stats["waterfill_rounds"],
+                        "waterfill_levels": step_stats["waterfill_levels"],
                         "truncated": step_stats["truncated"],
                         "skipped": step_stats["skipped"],
                     }

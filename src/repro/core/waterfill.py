@@ -1,19 +1,26 @@
-"""Exact tree water-filling: a combinatorial oracle (and fast path) for the
-max-min phases when no tenant SLAs are present.
+"""Exact tree water-filling: the max-min phases when no tenant SLAs are
+present.
 
 Progressive filling: raise all unsaturated devices in the optimized set at a
 uniform rate; when a device bound or node capacity binds, freeze the affected
 devices; repeat.  For box + tree-capacity feasible sets this produces the
 lexicographically max-min optimal allocation — the same limit the paper's
-iterated LP sequence (Algorithm 2) converges to.  Used (a) in tests to
-cross-validate Phases II/III against the LP path and (b) as the production
-fast path on the controller hot loop for SLA-free problems (a beyond-paper
-optimization recorded in EXPERIMENTS.md §Perf: it replaces an iterated
-50k-iteration LP solve at n = 12k with an exact O(depth * n * rounds) sweep).
+iterated LP sequence (Algorithm 2) converges to.  Per-round cost is
+O(n + m); the number of rounds is the number of distinct binding events,
+devices reaching their own bound included (up to ~1,300 on a 12k hall).
 
-Per-round cost is O(n + m); the number of rounds is bounded by the number of
-distinct binding events (<= number of nodes + 1), and in practice is ~tree
-depth.
+Three fills share these semantics:
+
+* :func:`waterfill_arrays` — the sweep in numpy: the host drivers' fast
+  path and the oracle the tests check the others against;
+* :func:`waterfill_jax` — the sweep as a ``lax.while_loop``: the fleet
+  coordinator's grant plans (:mod:`repro.fleet.coordinator`,
+  :mod:`repro.fleet.sharded`);
+* :func:`waterfill_project_jax` — the same raise as a level-wise tree
+  projection, whose cost follows the tree levels that bind, not the
+  devices that saturate: the jitted step's Phases II/III
+  (:func:`repro.core.batched.solve_three_phase`, so ``AllocEngine`` and
+  the fleet orchestrator's domain solves).
 """
 
 from __future__ import annotations
@@ -22,7 +29,7 @@ import numpy as np
 
 from repro.pdn.tree import FlatPDN
 
-__all__ = ["waterfill", "waterfill_arrays", "waterfill_jax"]
+__all__ = ["waterfill", "waterfill_arrays", "waterfill_jax", "waterfill_project_jax"]
 
 
 def waterfill_arrays(
@@ -135,6 +142,110 @@ def waterfill_jax(base, opt_mask, tree, u, max_rounds: int = 10_000):
             cond, body, (x0, live0, jnp.asarray(False), jnp.asarray(0, jnp.int32))
         )
     return x, rounds
+
+
+# Water levels tried per search step of waterfill_project_jax, as one
+# [SEARCH_CANDIDATES, n] evaluation that cuts a node's bracket 33-fold.  At
+# n = 12,288 in float64 on a TPU v5e a step costs ~0.35 ms at 24-64
+# candidates, while 16 compile to ~7x the code and cost 0.60 ms a step.
+SEARCH_CANDIDATES = 32
+SEARCH_STEP_CAP = 64  # per tree level; a bracket reaches one ulp in ~11-15
+
+
+def waterfill_project_jax(base, opt_mask, tree, u, n_depths: int):
+    """The max-min raise of :func:`waterfill_arrays` as a tree projection,
+    trace-safe under jit and vmap: its cost follows how many tree levels
+    bind, not how many devices saturate.
+
+    On a polymatroid (box plus nested caps) the max-min fair raise is the
+    least-norm raise: the projection of ``base + T`` (``T`` at or above every
+    raised device's head-room) onto the box ``[base, u]`` of the raised
+    devices (``opt_mask``; every other device held at ``base``) and the
+    tree's caps.  It is solved bottom-up, one tree level at a time, with
+    one water level ``lam = T - price`` per node: the largest ``lam >= 0``
+    with ``sum(min(lam, h_i)) <= cap - sum(base)`` over the node's devices,
+    ``h_i`` being each device's head-room with the deeper levels' water
+    levels folded in.  The level is found by a bracketing search over
+    ``SEARCH_CANDIDATES`` levels per step, vectorised over the nodes of a
+    tree level, until no bracket shrinks in the dtype; the feasible end is
+    kept, so every subtree sum stays at or below its cap, and a node over
+    its cap at entry leaves its raised devices at ``base``.  A tree level
+    where no node binds runs no search step.
+
+    ``n_depths`` (static) is the number of tree levels, root included.
+    Returns ``(x, steps, levels)``: the allocation, the search steps run
+    (int32; what the fill's time scales with) and the tree levels whose
+    search ran.  Runs under the ``waterfill`` named scope.
+    """
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from repro.core.treeops import tree_matvec, tree_rmatvec
+
+    x0 = jnp.asarray(base)
+    dtype = x0.dtype
+    n, m = x0.shape[0], tree.m
+    u = jnp.asarray(u, dtype)
+    zero = jnp.zeros((), jnp.int32)
+    frac = jnp.arange(1, SEARCH_CANDIDATES + 1, dtype=dtype) / (SEARCH_CANDIDATES + 1)
+    pad = jnp.zeros((SEARCH_CANDIDATES, 1), dtype)
+    inf = jnp.asarray(jnp.inf, dtype)
+
+    with jax.named_scope("waterfill"):
+        h0 = jnp.where(jnp.asarray(opt_mask, bool), jnp.maximum(u - x0, 0.0), 0.0)
+        room = tree.cap - tree_matvec(x0, tree)
+        # a node that holds all its devices at their upper bounds (a server
+        # carrying 8 x u) never binds, however its sums round
+        can_bind = tree_matvec(jnp.maximum(u, x0), tree) > tree.cap
+        # anc[d, i]: the depth-d node above device i, or m (the slot past
+        # the last node) where none is; exact, as an integer prefix sum
+        ids = jnp.where(
+            tree.depth[None, :] == jnp.arange(n_depths, dtype=jnp.int32)[:, None],
+            jnp.arange(1, m + 1, dtype=jnp.int32),
+            0,
+        )
+        anc = jax.vmap(lambda y: tree_rmatvec(y, tree, n))(ids) - 1
+        anc = jnp.where(anc < 0, m, anc)
+
+        def level(k, carry):
+            h, steps, levels = carry
+            d = n_depths - 1 - k  # deepest level first
+            above = anc[d]
+            need = tree_matvec(h, tree)  # subtree sums at lam = +inf
+            bind = (tree.depth == d) & can_bind & (need > 0) & (need > room)
+            # bracket [lo, hi]: lam = lo fits under the cap, hi overshoots it
+            lo = jnp.zeros((m,), dtype)
+            hi = jnp.where(bind & (room > 0), jnp.max(h), 0.0)
+
+            def cond(c):
+                _, _, live, s = c
+                return jnp.any(live) & (s < SEARCH_STEP_CAP)
+
+            def body(c):
+                lo, hi, live, s = c
+                cand = lo + (hi - lo) * frac[:, None]  # [SEARCH_CANDIDATES, m]
+                fill = jnp.minimum(jnp.concatenate([cand, pad], axis=1)[:, above], h)
+                fits = jax.vmap(lambda v: tree_matvec(v, tree))(fill) <= room
+                lo_new = jnp.maximum(lo, jnp.max(jnp.where(fits, cand, -inf), axis=0))
+                hi_new = jnp.minimum(hi, jnp.min(jnp.where(fits, inf, cand), axis=0))
+                hi_new = jnp.maximum(hi_new, lo_new)  # rounding may cross them
+                shrank = (lo_new != lo) | (hi_new != hi)
+                return (
+                    jnp.where(live, lo_new, lo),
+                    jnp.where(live, hi_new, hi),
+                    live & shrank & (hi_new > lo_new),
+                    s + 1,
+                )
+
+            init = (lo, hi, bind & (hi > lo), zero)
+            lam, _, _, s = lax.while_loop(cond, body, init)
+            lam = jnp.concatenate([jnp.where(bind, lam, inf), inf[None]])
+            return jnp.minimum(h, lam[above]), steps + s, levels + (s > 0)
+
+        h, steps, levels = lax.fori_loop(0, n_depths, level, (h0, zero, zero))
+        x = jnp.where(h > 0, jnp.minimum(x0 + h, u), x0)
+    return x, steps, levels
 
 
 def waterfill(

@@ -248,6 +248,8 @@ def _solve_domains(
             "iterations_p3": zi,
             "waterfill_rounds_p2": zi,
             "waterfill_rounds_p3": zi,
+            "waterfill_levels_p2": zi,
+            "waterfill_levels_p3": zi,
             "converged": yes,
             "kkt_certified": yes,
             "truncated": jnp.zeros((kk,), bool),
@@ -1363,7 +1365,7 @@ class FleetOrchestrator:
             else np.ones(self.k, bool)
         )
         allocs, solves, iters, phase_iters, conv = [], [], [], [], []
-        skipped, certify, wf_rounds = [], [], []
+        skipped, certify, wf_rounds, wf_levels = [], [], [], []
         certified, truncated, kkt_res, restarts, kkt_hist = [], [], [], [], []
         for k, eng in enumerate(self._engines):
             rk = req[offs[k] : offs[k + 1]]
@@ -1385,6 +1387,7 @@ class FleetOrchestrator:
                 iters.append(0)
                 phase_iters.append([0, 0, 0])
                 wf_rounds.append([0, 0])
+                wf_levels.append([0, 0])
                 conv.append(True)
                 skipped.append(True)
                 certify.append(True)
@@ -1404,6 +1407,7 @@ class FleetOrchestrator:
             iters.append(res.stats["total_iterations"])
             phase_iters.append(res.stats["phase_iterations"])
             wf_rounds.append(res.stats["waterfill_rounds"])
+            wf_levels.append(res.stats["waterfill_levels"])
             conv.append(res.stats["converged"])
             skipped.append(bool(res.stats.get("skipped", False)))
             certify.append(bool(res.stats.get("certify_pass", False)))
@@ -1441,6 +1445,7 @@ class FleetOrchestrator:
             restarts=np.asarray(restarts),
             kkt_hist=np.stack(kkt_hist, axis=0),
             waterfill_rounds=np.asarray(wf_rounds),
+            waterfill_levels=np.asarray(wf_levels),
             mode="loop",
         )
         return np.concatenate(allocs), stats

@@ -38,10 +38,12 @@ class StepStats(dict):
     Canonical fields: ``solves``, ``iterations``, ``phase_iterations``
     (``[3]`` or ``[K, 3]``), ``converged``, ``skipped``, ``certify_pass``,
     and (when the producing path reports them) ``kkt_certified``,
-    ``truncated``, ``kkt_res``, ``restarts``, ``kkt_hist`` and
-    ``waterfill_rounds`` (``[2]`` or ``[K, 2]``: rounds of the max-min
-    waterfill in Phases II and III, the SLA-free path that runs no PDHG
-    iteration).  Values are Python scalars on the engine path and numpy
+    ``truncated``, ``kkt_res``, ``restarts``, ``kkt_hist``,
+    ``waterfill_rounds`` and ``waterfill_levels`` (each ``[2]`` or
+    ``[K, 2]``, Phases II and III, on the SLA-free path that runs no PDHG
+    iteration: the sequential search steps of the max-min fill, which its
+    device time scales with, and the tree levels whose search ran, 0 where
+    no node binds).  Values are Python scalars on the engine path and numpy
     arrays on batched/fleet paths — the record is shape-agnostic on purpose.
     """
 
@@ -61,6 +63,7 @@ class StepStats(dict):
         restarts: Any = None,
         kkt_hist: Any = None,
         waterfill_rounds: Any = None,
+        waterfill_levels: Any = None,
         **extras: Any,
     ) -> "StepStats":
         out = cls()
@@ -77,6 +80,7 @@ class StepStats(dict):
             "restarts": restarts,
             "kkt_hist": kkt_hist,
             "waterfill_rounds": waterfill_rounds,
+            "waterfill_levels": waterfill_levels,
         }
         for name, value in fields.items():
             if value is None:
@@ -95,7 +99,7 @@ class StepStats(dict):
         """Convert the traced stats dict of
         :func:`repro.core.batched.solve_three_phase` (keys ``solves``,
         ``iterations``, ``iterations_p1..3``, ``waterfill_rounds_p2..3``,
-        flags) to host values.
+        ``waterfill_levels_p2..3``, flags) to host values.
 
         ``scalar=True`` is the engine (K=1) path: leaves become Python
         ``int``/``bool``/``float`` scalars, matching the pre-PR-8 engine
@@ -104,8 +108,9 @@ class StepStats(dict):
         pi = np.stack(
             [np.asarray(stats[f"iterations_p{i}"]) for i in (1, 2, 3)], axis=-1
         )
-        wr = np.stack(
-            [np.asarray(stats[f"waterfill_rounds_p{i}"]) for i in (2, 3)], axis=-1
+        wr, wl = (
+            np.stack([np.asarray(stats[f"{key}_p{i}"]) for i in (2, 3)], axis=-1)
+            for key in ("waterfill_rounds", "waterfill_levels")
         )
         if scalar:
             return cls.build(
@@ -121,6 +126,7 @@ class StepStats(dict):
                 restarts=int(stats["restarts"]),
                 kkt_hist=np.asarray(stats["kkt_hist"]),
                 waterfill_rounds=[int(v) for v in wr],
+                waterfill_levels=[int(v) for v in wl],
                 **extras,
             )
         return cls.build(
@@ -136,6 +142,7 @@ class StepStats(dict):
             restarts=np.asarray(stats["restarts"]),
             kkt_hist=np.asarray(stats["kkt_hist"]),
             waterfill_rounds=wr,
+            waterfill_levels=wl,
             **extras,
         )
 

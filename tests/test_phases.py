@@ -3,16 +3,24 @@ waterfill <-> iterated-LP equivalence."""
 
 from __future__ import annotations
 
+import math
+
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import pdhg, phases
 from repro.core.nvpax import NvpaxOptions, optimize
 from repro.core.problem import AllocProblem
 from repro.core.treeops import TreeTopo
-from repro.core.waterfill import waterfill, waterfill_arrays, waterfill_jax
+from repro.core.waterfill import (
+    waterfill,
+    waterfill_arrays,
+    waterfill_jax,
+    waterfill_project_jax,
+)
 from repro.pdn.tree import build_from_level_sizes
 
 pytestmark = pytest.mark.usefixtures("x64")
@@ -171,19 +179,154 @@ def test_waterfill_jax_counts_one_round_per_binding_event():
     )
 
 
-def test_engine_reports_the_waterfill_rounds_of_phases_2_and_3(small_pdn):
-    """``stats["waterfill_rounds"]`` is what a direct sweep from each phase's
-    start counts: Phase II raises the active devices from the Phase I caps,
-    Phase III the idle ones from the Phase II caps."""
+def _topo(start, end, cap, depth):
+    return TreeTopo(
+        jnp.asarray(start, jnp.int32),
+        jnp.asarray(end, jnp.int32),
+        jnp.asarray(cap, jnp.float64),
+        jnp.asarray(depth, jnp.int32),
+    )
+
+
+def _uniform_case(depth, seed):
+    """A uniform tree ``depth`` node levels deep (root included) with random
+    fan-outs and a random raised set; each of the root's children has a
+    heat of its own, so a hot subtree binds below the root's water level."""
+    rng = np.random.default_rng(seed)
+    pdn = build_from_level_sizes(
+        list(rng.integers(2, 4, depth - 1)), gpus_per_server=int(rng.integers(2, 6))
+    )
+    tops = pdn.node_start[pdn.node_depth == 1]
+    top = np.searchsorted(tops, np.arange(pdn.n), "right") - 1
+    heat = rng.uniform(0.0, 1.0, tops.size)[top]
+    base = pdn.dev_l + heat * rng.uniform(0.5, 0.95, pdn.n) * (pdn.dev_u - pdn.dev_l)
+    opt = rng.random(pdn.n) < 0.7
+    tree = (pdn.node_start, pdn.node_end, pdn.node_cap, pdn.node_depth)
+    return tree, pdn.dev_u, base[None], opt[None]
+
+
+def _fill_case(name):
+    """(tree arrays, u, bases [L, n], raised sets [L, n]) of one case; L > 1
+    runs the lanes under one ``vmap``."""
+    if name == "two_rack":
+        start, end, cap, u = _two_rack_tree()
+        tree = (start, end, cap, [0, 1, 1])
+        return tree, u, np.zeros((1, 4)), np.ones((1, 4), bool)
+    if name == "hall_rounding":
+        # as test_waterfill_freezes_binding_node_despite_rounding: 5e7 W bases
+        start, end = np.array([0, 0, 2]), np.array([4, 2, 4])
+        base = np.array([50000000.22715759, 50000000.62318715, 100.0, 100.0])
+        cap_a = 100000001.69049819
+        cap = np.array([cap_a + 1000.0, cap_a, 1e9])
+        tree = (start, end, cap, [0, 1, 1])
+        return tree, np.full(4, 1e9), base[None], np.ones((1, 4), bool)
+    if name == "over_cap_at_entry":
+        # rack A's bases already exceed its cap; rack B fills to the root's
+        start, end = np.array([0, 0, 3]), np.array([6, 3, 6])
+        cap = np.array([2600.0, 900.0, 2000.0])
+        base = np.array([400.0, 300.0, 300.0, 200.0, 200.0, 200.0])
+        tree = (start, end, cap, [0, 1, 1])
+        return tree, np.full(6, 700.0), base[None], np.ones((1, 6), bool)
+    if name == "mixed_u":
+        pdn = build_from_level_sizes([2, 3], gpus_per_server=4)
+        rng = np.random.default_rng(11)
+        u = rng.uniform(300.0, 900.0, pdn.n)
+        base = rng.uniform(150.0, 250.0, pdn.n)
+        tree = (pdn.node_start, pdn.node_end, pdn.node_cap, pdn.node_depth)
+        return tree, u, base[None], np.ones((1, pdn.n), bool)
+    if name == "partial_mask":
+        pdn = build_from_level_sizes([2, 2, 2], gpus_per_server=4)
+        rng = np.random.default_rng(12)
+        base = rng.uniform(200.0, 600.0, pdn.n)
+        opt = np.zeros(pdn.n, bool)
+        opt[::3] = True
+        tree = (pdn.node_start, pdn.node_end, pdn.node_cap, pdn.node_depth)
+        return tree, pdn.dev_u, base[None], opt[None]
+    if name.startswith("uniform"):
+        _, depth, seed = name.split("-")
+        return _uniform_case(int(depth[1:]), int(seed[1:]))
+    assert name == "vmap_lanes"
+    pdn = build_from_level_sizes([3, 2, 2], gpus_per_server=4)
+    rng = np.random.default_rng(13)
+    bases = rng.uniform(200.0, 650.0, (4, pdn.n))
+    bases[1] = pdn.dev_l  # an idle placement, every device at its floor
+    opts = rng.random((4, pdn.n)) < np.array([[0.9], [1.0], [0.5], [0.2]])
+    tree = (pdn.node_start, pdn.node_end, pdn.node_cap, pdn.node_depth)
+    return tree, pdn.dev_u, bases, opts
+
+
+@pytest.mark.parametrize(
+    "case",
+    [
+        "two_rack",
+        "hall_rounding",
+        "over_cap_at_entry",
+        "mixed_u",
+        "partial_mask",
+        "uniform-d2-s0",
+        "uniform-d3-s1",
+        "uniform-d4-s2",
+        "uniform-d3-s3",
+        "uniform-d4-s4",
+        "vmap_lanes",
+    ],
+)
+def test_waterfill_project_matches_the_sweep(case):
+    """The level-wise tree projection gives the numpy sweep's allocation to
+    1e-6 W, and adds nothing above any cap: each subtree sum stays at or
+    below its cap, or at its bases' sum where that was over the cap already."""
+    (start, end, cap, depth), u, bases, opts = _fill_case(case)
+    start, end, cap, u = map(np.asarray, (start, end, cap, u))
+    tree = _topo(start, end, cap, depth)
+    n_depths = int(np.max(depth)) + 1
+
+    def fill(b, o):
+        return waterfill_project_jax(b, o, tree, jnp.asarray(u), n_depths)
+
+    xs, steps, levels = jax.jit(jax.vmap(fill))(jnp.asarray(bases), jnp.asarray(opts))
+    for x, base, opt in zip(np.asarray(xs), bases, opts):
+        want = waterfill_arrays(start, end, cap, u, base, opt)
+        np.testing.assert_allclose(x, want, rtol=0, atol=1e-6)
+        np.testing.assert_array_equal(x[~opt], base[~opt])
+        sums = [math.fsum(x[a:b]) for a, b in zip(start, end)]
+        base_sums = [math.fsum(base[a:b]) for a, b in zip(start, end)]
+        assert (np.array(sums) <= np.maximum(cap, base_sums) + 1e-6).all()
+    assert (np.asarray(levels) <= n_depths).all()
+    assert ((np.asarray(steps) > 0) == (np.asarray(levels) > 0)).all()
+
+
+def _engine_step_and_direct_fills(pdn):
+    """One engine step, and the (steps, levels) of a direct fill from each
+    max-min phase's start: Phase II raises the active devices from the
+    Phase I caps, Phase III the idle ones from the Phase II caps."""
     from repro.core.engine import AllocEngine
 
-    eng = AllocEngine(small_pdn)
-    tele = np.random.default_rng(5).uniform(50.0, 800.0, small_pdn.n)
+    eng = AllocEngine(pdn)
+    tele = np.random.default_rng(5).uniform(50.0, 800.0, pdn.n)
     res = eng.step(tele)
     active = jnp.asarray(tele >= eng.idle_threshold)
-    tree, u = eng.fleet.tree, eng.fleet.u
-    _, r2 = waterfill_jax(jnp.asarray(res.phase1), active, tree, u)
-    _, r3 = waterfill_jax(jnp.asarray(res.phase2), ~active, tree, u)
-    assert res.stats["waterfill_rounds"] == [int(r2), int(r3)]
-    assert int(r2) > 0
+    tree, u, nd = eng.fleet.tree, eng.fleet.u, eng.meta.n_depths
+    _, s2, l2 = waterfill_project_jax(jnp.asarray(res.phase1), active, tree, u, nd)
+    _, s3, l3 = waterfill_project_jax(jnp.asarray(res.phase2), ~active, tree, u, nd)
     assert eng.history[-1]["waterfill_rounds"] == res.stats["waterfill_rounds"]
+    assert eng.history[-1]["waterfill_levels"] == res.stats["waterfill_levels"]
+    return res.stats, [int(s2), int(s3)], [int(l2), int(l3)], nd
+
+
+def test_engine_reports_the_waterfill_rounds_of_phases_2_and_3(small_pdn):
+    """``stats["waterfill_rounds"]`` (search steps) and
+    ``stats["waterfill_levels"]`` are what a direct fill from each phase's
+    start counts."""
+    stats, steps, levels, nd = _engine_step_and_direct_fills(small_pdn)
+    assert stats["waterfill_rounds"] == steps
+    assert stats["waterfill_levels"] == levels
+    assert steps[0] > 0 and 1 <= levels[0] <= nd
+
+
+def test_engine_reports_no_waterfill_search_where_no_node_binds():
+    """With every cap the sum of its children's (oversubscription 1) no node
+    can bind: no level is searched and no search step runs."""
+    pdn = build_from_level_sizes([2, 3, 2], gpus_per_server=4, oversubscription=1.0)
+    stats, steps, levels, _ = _engine_step_and_direct_fills(pdn)
+    assert stats["waterfill_rounds"] == steps == [0, 0]
+    assert stats["waterfill_levels"] == levels == [0, 0]
