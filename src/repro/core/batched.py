@@ -501,7 +501,7 @@ def solve_three_phase(
         with jax.named_scope("phase2"):
             p2, cut2 = refine(x1, w2, ap.active, ap.idle, p1.iterations)
         if carry is not None:
-            p2 = p2._replace(x=jnp.where(skip, dec.x_snap, p2.x))
+            p2 = p2._replace(x=jnp.where(skip, carry.x2, p2.x))
         x2 = p2.x
         truncated = truncated | cut2
     else:
@@ -645,6 +645,7 @@ def _solve_batched(
             carry_one,
             ap,
             x1,
+            x2,
             x3,
             stats["skipped"],
             stats["certify_pass"] & ~stats["skipped"],
@@ -715,7 +716,7 @@ def _solve_batched(
             "kkt_hist": jnp.zeros((kk, KKT_HIST_BUCKETS), jnp.int32),
         }
         wcarry = phases.WarmCarry(p1_sol, w2, w3)
-        return carry.x1, dec.x_snap, dec.x_snap, wcarry, stats, carry
+        return carry.x1, carry.x2, dec.x_snap, wcarry, stats, carry
 
     def slow(_):
         return run_vmapped(carry)

@@ -123,7 +123,8 @@ def _engine_solve(
     )
     x1, x2, x3, sol, stats = solve_three_phase(ap, meta, opts, warm, iter_budget, carry)
     new_carry = certify.update_carry(
-        carry, ap, x1, x3, stats["skipped"], stats["certify_pass"] & ~stats["skipped"]
+        carry, ap, x1, x2, x3, stats["skipped"],
+        stats["certify_pass"] & ~stats["skipped"],
     )
     if rec is not None and rec_cfg is not None:
         nrows = int(fleet.sla.lo.shape[0])

@@ -123,7 +123,7 @@ def optimize(
             return AllocResult(
                 allocation=np.asarray(x3),
                 phase1=np.asarray(carry.x1),
-                phase2=np.asarray(x3),
+                phase2=np.asarray(carry.x2),
                 warm_state=warm,
                 wall_time_s=time.perf_counter() - t0,
                 stats=StepStats.build(
@@ -185,6 +185,7 @@ def optimize(
         if options.incremental:
             if p1_reused:
                 new_carry = carry._replace(
+                    x2=jnp.asarray(x2),
                     x=jnp.asarray(x3),
                     cap=ap.tree.cap,
                     sla_lo=ap.sla.lo,
@@ -192,7 +193,7 @@ def optimize(
                 )
             else:
                 new_carry = solver_mod.make_carry(
-                    ap, jnp.asarray(x1), jnp.asarray(x3)
+                    ap, jnp.asarray(x1), jnp.asarray(x2), jnp.asarray(x3)
                 )
     wall = time.perf_counter() - t0
     return AllocResult(

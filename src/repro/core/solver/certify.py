@@ -11,7 +11,8 @@ skipped in O(matvec).
 The certificate has two tiers, both fully traced (fixed shapes, no
 recompilation across skip/solve transitions):
 
-* **full skip** — the carried final allocation is returned unchanged.
+* **full skip** — the carried final allocation is returned unchanged,
+  with the anchor's Phase I and Phase II allocations as its phases.
   Sound when the binding-set fingerprint is unchanged — same active mask,
   box edges, tree caps and SLA rows within ``certify_tol`` watts — and
   every shaped demand is held within ``certify_tol`` of the anchor value
@@ -59,6 +60,7 @@ class IncrementalCarry(NamedTuple):
     """
 
     x1: jnp.ndarray  # [n] Phase I allocation of the anchor solve
+    x2: jnp.ndarray  # [n] Phase II allocation of the anchor solve
     x: jnp.ndarray  # [n] final feasible allocation
     r: jnp.ndarray  # [n] shaped requests the anchor was solved against
     active: jnp.ndarray  # [n] bool activity mask
@@ -78,10 +80,13 @@ class CertifyDecision(NamedTuple):
     feas_res: jnp.ndarray  # max primal-feasibility violation of x_snap (watts)
 
 
-def make_carry(ap: AllocProblem, x1: jnp.ndarray, x3: jnp.ndarray) -> IncrementalCarry:
+def make_carry(
+    ap: AllocProblem, x1: jnp.ndarray, x2: jnp.ndarray, x3: jnp.ndarray
+) -> IncrementalCarry:
     """Snapshot a freshly solved step as the next certify anchor."""
     return IncrementalCarry(
         x1=x1,
+        x2=x2,
         x=x3,
         r=ap.r,
         active=ap.active,
@@ -188,13 +193,15 @@ def update_carry(
     carry: IncrementalCarry | None,
     ap: AllocProblem,
     x1: jnp.ndarray,
+    x2: jnp.ndarray,
     x3: jnp.ndarray,
     skipped: jnp.ndarray,
     p1_reused: jnp.ndarray,
 ) -> IncrementalCarry:
     """Next-step anchor: frozen on a full skip, Phase-I-anchored on a Phase I
-    skip (new caps + new final allocation), fresh after a full solve."""
-    fresh = make_carry(ap, x1, x3)
+    skip (new caps + new Phase II and final allocations), fresh after a full
+    solve."""
+    fresh = make_carry(ap, x1, x2, x3)
     if carry is None:
         return fresh
     keep_p1 = skipped | p1_reused
@@ -204,6 +211,7 @@ def update_carry(
 
     return IncrementalCarry(
         x1=sel(keep_p1, carry.x1, fresh.x1),
+        x2=sel(skipped, carry.x2, fresh.x2),
         x=sel(skipped, carry.x, fresh.x),
         r=sel(keep_p1, carry.r, fresh.r),
         active=fresh.active,

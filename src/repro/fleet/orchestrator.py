@@ -175,6 +175,7 @@ def _solve_domains(
             carry_k,
             ap,
             x1,
+            x2,
             x3,
             stats["skipped"],
             stats["certify_pass"] & ~stats["skipped"],
@@ -262,7 +263,7 @@ def _solve_domains(
             ),
         }
         wcarry = phases.WarmCarry(p1_sol, w2, w3)
-        return carry.x1, dec.x_snap, dec.x_snap, wcarry, stats, carry
+        return carry.x1, carry.x2, dec.x_snap, wcarry, stats, carry
 
     def slow(_):
         return run_vmapped(carry)
@@ -293,6 +294,8 @@ class FleetStepResult:
     """One fleet control step: global allocation + coordinator decisions."""
 
     allocation: np.ndarray  # [n] global device order (domain concatenation)
+    phase1: np.ndarray  # [n] Phase I caps, same order
+    phase2: np.ndarray  # [n] Phase II caps, same order
     grants: np.ndarray  # [K] coordinator budget grants (watts)
     demand: np.ndarray  # [K] per-domain aggregate shaped demand (watts)
     wall_time_s: float
@@ -1038,8 +1041,9 @@ class FleetOrchestrator:
     ) -> FleetStepResult:
         """One fleet control step: telemetry [n] watts -> allocation [n].
 
-        Telemetry and the returned allocation are in global device order
-        (domain concatenation).  Host-side work is O(n) request shaping,
+        Telemetry, the returned allocation and the Phase I and Phase II
+        caps (``phase1``/``phase2``) are in global device order (domain
+        concatenation), in every dispatch mode.  Host-side work is O(n) request shaping,
         the O(K + m_above_cut) coordinator plan, and the scatter/gather
         into the per-domain layout; all solves are compiled programs.
         """
@@ -1082,27 +1086,35 @@ class FleetOrchestrator:
                         req, active, grants, offs, row_bounds, demand
                     )
             wall = time.perf_counter() - t0
+        alloc, phase1, phase2, stats = res
         if slice_lo is not None:
-            res[1]["slice_lo"] = slice_lo
-            res[1]["slice_hi"] = slice_hi
+            stats["slice_lo"] = slice_lo
+            stats["slice_hi"] = slice_hi
         out = FleetStepResult(
-            allocation=res[0],
+            allocation=alloc,
+            phase1=phase1,
+            phase2=phase2,
             grants=grants,
             demand=demand,
             wall_time_s=wall,
-            stats=res[1],
+            stats=stats,
         )
-        self.history.append(
-            {
-                "wall_s": wall,
-                "converged": bool(np.all(out.stats["converged"])),
-                "solves": int(np.sum(out.stats["solves"])),
-                "iterations": int(np.sum(out.stats["iterations"])),
-                "granted_W": float(grants.sum()),
-                "demand_W": float(demand.sum()),
-                "skipped": int(np.sum(out.stats.get("skipped", False))),
-            }
-        )
+        iters = np.asarray(stats["iterations"])
+        entry = {
+            "wall_s": wall,
+            "converged": bool(np.all(stats["converged"])),
+            "solves": int(np.sum(stats["solves"])),
+            "iterations": int(iters.sum()),
+            # the domains step in lockstep: the slowest sets the step
+            "iterations_max": int(iters.max()),
+            "iterations_min": int(iters.min()),
+            "granted_W": float(grants.sum()),
+            "demand_W": float(demand.sum()),
+            "skipped": int(np.sum(stats.get("skipped", False))),
+        }
+        if "coordinator_rounds" in stats:
+            entry["coordinator_rounds"] = int(stats["coordinator_rounds"])
+        self.history.append(entry)
         return out
 
     @property
@@ -1134,14 +1146,20 @@ class FleetOrchestrator:
                 lanes.append(f["step"] if f is not None and "step" in f else {})
         return {"mode": self.mode, "lanes": lanes}
 
-    def _step_stacked(self, req, active, grants, offs, row_bounds=None):
-        K, N = self.k, self._N
-        r = np.zeros((K, N))
-        act = np.zeros((K, N), bool)
-        for k in range(K):
+    def _scatter(self, req, active, offs):
+        """Global ``[n]`` telemetry and activity into the padded ``[K, N]``
+        per-domain layout."""
+        r = np.zeros((self.k, self._N))
+        act = np.zeros((self.k, self._N), bool)
+        for k in range(self.k):
             nk = int(self.domain_sizes[k])
             r[k, :nk] = req[offs[k] : offs[k + 1]]
             act[k, :nk] = active[offs[k] : offs[k + 1]]
+        return r, act
+
+    def _step_stacked(self, req, active, grants, offs, row_bounds=None):
+        K, N = self.k, self._N
+        r, act = self._scatter(req, active, offs)
         cap = self._cap_np.copy()
         cap[:, 0] = grants
         # per-step SLA rows: real rows get contract/sub-budget bounds, pad
@@ -1172,7 +1190,7 @@ class FleetOrchestrator:
                 opts=self.options.solver,
                 rec_cfg=self._rec_cfg,
             )
-            x3 = np.asarray(x3.block_until_ready())
+            x3.block_until_ready()
         if new_rec is not None:
             self._rec_state = new_rec
         self._warm = warm_c
@@ -1180,11 +1198,19 @@ class FleetOrchestrator:
             # update_carry(None, ...) seeds a fresh anchor on the first
             # step, so new_inc is a [K, ...]-leaf carry on every path
             self._inc_carry = new_inc
-        alloc = np.concatenate([x3[k, : int(self.domain_sizes[k])] for k in range(K)])
-        return alloc, self._batched_stats(stats, "stacked")
+        return (
+            *self._gather(x3, x1, x2),
+            StepStats.from_jit(stats, mode="stacked"),
+        )
 
-    def _batched_stats(self, stats, mode: str) -> StepStats:
-        return StepStats.from_jit(stats, mode=mode)
+    def _gather(self, *xs) -> tuple[np.ndarray, ...]:
+        """Each padded ``[K, N]`` device array to host ``[n]`` in global
+        device order (the domain concatenation)."""
+        sizes = [int(nk) for nk in self.domain_sizes]
+        return tuple(
+            np.concatenate([row[:nk] for row, nk in zip(x, sizes)])
+            for x in jax.device_get(xs)
+        )
 
     def _sharded_plan(self):
         """(PlanRep, RowMaps | None): demand-independent planning arrays for
@@ -1263,27 +1289,35 @@ class FleetOrchestrator:
         return rep, rowmap
 
     def _step_sharded(self, req, active, offs):
+        """The sharded step under ``fleet.dispatch``, its host stages as
+        spans: ``fleet.scatter`` (telemetry into the padded ``[K, N]``
+        layout), ``fleet.plan`` (the demand-free planning arrays),
+        ``fleet.upload`` (per-step inputs to the devices), the program's
+        call, ``fleet.wait`` (until the caps are computed), ``fleet.fetch``
+        (the three phases' caps to host, in global device order) and
+        ``fleet.stats``."""
         from repro.fleet import sharded as shd
 
         K, N = self.k, self._N
-        r = np.zeros((K, N))
-        act = np.zeros((K, N), bool)
-        for k in range(K):
-            nk = int(self.domain_sizes[k])
-            r[k, :nk] = req[offs[k] : offs[k + 1]]
-            act[k, :nk] = active[offs[k] : offs[k + 1]]
+        with spans.span("fleet.scatter"):
+            r, act = self._scatter(req, active, offs)
         inc = self._inc_carry if self.options.incremental else None
         with self._ctx():
             if self._rec_cfg is not None and self._rec_state is None:
                 self._rec_state = obs_recorder.init_batch(
                     self._rec_cfg, K, N, self.dtype
                 )
-            rep, rowmap = self._sharded_plan()
-            x3, warm_c, stats, new_inc, grants, demand, slo, shi, new_rec = shd.step(
+            with spans.span("fleet.plan"):
+                rep, rowmap = self._sharded_plan()
+            with spans.span("fleet.upload"):
+                cap = jnp.asarray(self._cap_np, self.dtype)
+                r_dev = jnp.asarray(r, self.dtype)
+                act_dev = jnp.asarray(act)
+            out = shd.step(
                 self._dom,
-                jnp.asarray(self._cap_np, self.dtype),
-                jnp.asarray(r, self.dtype),
-                jnp.asarray(act),
+                cap,
+                r_dev,
+                act_dev,
                 rowmap,
                 self._warm,
                 inc,
@@ -1295,23 +1329,31 @@ class FleetOrchestrator:
                 coord_mode=self.coordinator.mode,
                 rec_cfg=self._rec_cfg,
             )
-            x3 = np.asarray(x3.block_until_ready())
-        self._warm = warm_c
+            with spans.span("fleet.wait"):
+                out.x3.block_until_ready()
+        self._warm = out.warm
         if self.options.incremental:
-            self._inc_carry = new_inc
-        if new_rec is not None:
-            self._rec_state = new_rec
-        alloc = np.concatenate([x3[k, : int(self.domain_sizes[k])] for k in range(K)])
+            self._inc_carry = out.carry
+        if out.rec is not None:
+            self._rec_state = out.rec
+        with spans.span("fleet.fetch"):
+            alloc, phase1, phase2 = self._gather(out.x3, out.x1, out.x2)
+            grants, demand, slo, shi = jax.device_get(
+                (out.grants, out.demand, out.slice_lo, out.slice_hi)
+            )
+        with spans.span("fleet.stats"):
+            stats = StepStats.from_jit(
+                out.stats,
+                mode="sharded",
+                coordinator_rounds=int(out.coordinator_rounds),
+            )
         has_slices = self._sla is not None and self._sla.n_slices > 0
         return (
-            (
-                alloc,
-                self._batched_stats(stats, "sharded"),
-            ),
-            np.asarray(grants),
-            np.asarray(demand),
-            np.asarray(slo) if has_slices else None,
-            np.asarray(shi) if has_slices else None,
+            (alloc, phase1, phase2, stats),
+            grants,
+            demand,
+            slo if has_slices else None,
+            shi if has_slices else None,
         )
 
     def _loop_domain_clean(self, k, prev, rk, ak, grant_k, rb_k, tol) -> bool:
@@ -1346,6 +1388,8 @@ class FleetOrchestrator:
             K = self.k
             self._loop_prev = {
                 "alloc": [None] * K,
+                "phase1": [None] * K,
+                "phase2": [None] * K,
                 "req": [None] * K,
                 "active": [None] * K,
                 "demand": np.full(K, np.nan),
@@ -1364,7 +1408,8 @@ class FleetOrchestrator:
             if inc and demand is not None
             else np.ones(self.k, bool)
         )
-        allocs, solves, iters, phase_iters, conv = [], [], [], [], []
+        allocs, phase1, phase2 = [], [], []
+        solves, iters, phase_iters, conv = [], [], [], []
         skipped, certify, wf_rounds, wf_levels = [], [], [], []
         certified, truncated, kkt_res, restarts, kkt_hist = [], [], [], [], []
         for k, eng in enumerate(self._engines):
@@ -1383,6 +1428,8 @@ class FleetOrchestrator:
                 # clean domain: serve the frozen allocation, skip the engine
                 # dispatch entirely (the anchor values stay frozen too)
                 allocs.append(prev["alloc"][k])
+                phase1.append(prev["phase1"][k])
+                phase2.append(prev["phase2"][k])
                 solves.append(0)
                 iters.append(0)
                 phase_iters.append([0, 0, 0])
@@ -1403,6 +1450,8 @@ class FleetOrchestrator:
                 eng.set_sla_bounds(rb_k[0], rb_k[1])
             res = eng.step(rk, active=ak)
             allocs.append(res.allocation)
+            phase1.append(res.phase1)
+            phase2.append(res.phase2)
             solves.append(res.stats["total_solves"])
             iters.append(res.stats["total_iterations"])
             phase_iters.append(res.stats["phase_iterations"])
@@ -1424,6 +1473,8 @@ class FleetOrchestrator:
             )
             if inc:
                 prev["alloc"][k] = res.allocation
+                prev["phase1"][k] = res.phase1
+                prev["phase2"][k] = res.phase2
                 prev["req"][k] = rk.copy()
                 prev["active"][k] = ak.copy()
                 if demand is not None:
@@ -1448,4 +1499,9 @@ class FleetOrchestrator:
             waterfill_levels=np.asarray(wf_levels),
             mode="loop",
         )
-        return np.concatenate(allocs), stats
+        return (
+            np.concatenate(allocs),
+            np.concatenate(phase1),
+            np.concatenate(phase2),
+            stats,
+        )

@@ -50,7 +50,15 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.core.treeops import TreeTopo
 from repro.core.waterfill import waterfill_jax
 
-__all__ = ["PlanRep", "RowMaps", "build_mesh", "shard_count", "step", "trace_count"]
+__all__ = [
+    "PlanRep",
+    "RowMaps",
+    "StepOut",
+    "build_mesh",
+    "shard_count",
+    "step",
+    "trace_count",
+]
 
 _AXIS = "domains"
 
@@ -115,6 +123,24 @@ class PlanRep(NamedTuple):
     b_max_c: jnp.ndarray  # [Tc] cross-cut tenant contractual maxima
 
 
+class StepOut(NamedTuple):
+    """One sharded step's outputs: ``[K, ...]`` leaves sharded on the domain
+    axis, the coordinator's outputs replicated."""
+
+    x1: jnp.ndarray  # [K, N] Phase I caps
+    x2: jnp.ndarray  # [K, N] Phase II caps
+    x3: jnp.ndarray  # [K, N] final caps
+    warm: object  # phases.WarmCarry, [K, ...] leaves
+    stats: dict  # per-domain solver stats, [K] leaves
+    carry: object  # incremental anchor ([K, ...] leaves) or None
+    grants: jnp.ndarray  # [K] coordinator budget grants
+    demand: jnp.ndarray  # [K] per-domain shaped demand
+    slice_lo: jnp.ndarray  # [S] tenant slice floors
+    slice_hi: jnp.ndarray  # [S] tenant slice sub-budgets
+    rec: object  # flight-recorder state ([K, ...] leaves) or None
+    coordinator_rounds: jnp.ndarray  # int32: rounds of the two grant passes
+
+
 def _sharded_solve(
     dom, cap, r, active, rowmap, warm, carry, rep, rec,
     *, meta, opts, coord_mode, k_total, rec_cfg,
@@ -129,84 +155,89 @@ def _sharded_solve(
     dt = dom.l.dtype
     k_loc = dom.l.shape[0]
     idx = lax.axis_index(_AXIS)
-    shaped = jnp.where(active, jnp.clip(r, dom.l, dom.u), dom.l)
-    demand_loc = jnp.sum(shaped, axis=1)
     S = rep.slice_lo.shape[0]
 
-    # -- the one cross-shard reduction: [K] demand (+ [S] slice demand) ----
-    agg = jnp.zeros((k_total + S,), dt)
-    agg = lax.dynamic_update_slice(agg, demand_loc, (idx * k_loc,))
-    if S:
-        T = rowmap.lo_local.shape[1]
+    with jax.named_scope("coordinator"):
+        shaped = jnp.where(active, jnp.clip(r, dom.l, dom.u), dom.l)
+        demand_loc = jnp.sum(shaped, axis=1)
 
-        def rowsum(sh, dev, ten):
-            return jax.ops.segment_sum(sh[dev], ten, num_segments=T)
+        # -- the one cross-shard reduction: [K] demand (+ [S] slice demand)
+        agg = jnp.zeros((k_total + S,), dt)
+        agg = lax.dynamic_update_slice(agg, demand_loc, (idx * k_loc,))
+        if S:
+            T = rowmap.lo_local.shape[1]
 
-        row_demand = jax.vmap(rowsum)(shaped, dom.sla_dev, dom.sla_ten)
-        part = jnp.zeros((S + 1,), dt)
-        part = part.at[rowmap.slice_idx.reshape(-1)].add(row_demand.reshape(-1))
-        agg = agg.at[k_total:].add(part[:S])
-    agg = lax.psum(agg, _AXIS)
-    demand = agg[:k_total]
+            def rowsum(sh, dev, ten):
+                return jax.ops.segment_sum(sh[dev], ten, num_segments=T)
 
-    # -- replicated coordinator plan (waterfill over the above-cut tree) ---
-    ctree = TreeTopo(
-        start=rep.coord_start,
-        end=rep.coord_end,
-        cap=rep.ccap,
-        depth=jnp.zeros(rep.ccap.shape[0], jnp.int32),
-    )
-    mask_k = jnp.ones((k_total,), bool)
-    grants = rep.dmin_tot
-    if coord_mode == "waterfill":
-        grants, _ = waterfill_jax(
-            grants, mask_k, ctree, jnp.clip(demand, rep.dmin_tot, rep.dcap)
+            row_demand = jax.vmap(rowsum)(shaped, dom.sla_dev, dom.sla_ten)
+            part = jnp.zeros((S + 1,), dt)
+            part = part.at[rowmap.slice_idx.reshape(-1)].add(row_demand.reshape(-1))
+            agg = agg.at[k_total:].add(part[:S])
+        agg = lax.psum(agg, _AXIS)
+        demand = agg[:k_total]
+
+        # -- replicated coordinator plan (waterfill over the above-cut tree)
+        ctree = TreeTopo(
+            start=rep.coord_start,
+            end=rep.coord_end,
+            cap=rep.ccap,
+            depth=jnp.zeros(rep.ccap.shape[0], jnp.int32),
         )
-    grants, _ = waterfill_jax(grants, mask_k, ctree, rep.dcap)
+        mask_k = jnp.ones((k_total,), bool)
+        grants = rep.dmin_tot
+        rounds = jnp.zeros((), jnp.int32)
+        if coord_mode == "waterfill":
+            grants, rounds = waterfill_jax(
+                grants, mask_k, ctree, jnp.clip(demand, rep.dmin_tot, rep.dcap)
+            )
+        grants, headroom_rounds = waterfill_jax(grants, mask_k, ctree, rep.dcap)
+        rounds = rounds + headroom_rounds
 
-    if S:
-        slice_demand = agg[k_total:]
-        forest = TreeTopo(
-            start=rep.ten_start,
-            end=rep.ten_end,
-            cap=rep.b_max_c,
-            depth=jnp.zeros(rep.b_max_c.shape[0], jnp.int32),
+        if S:
+            slice_demand = agg[k_total:]
+            forest = TreeTopo(
+                start=rep.ten_start,
+                end=rep.ten_end,
+                cap=rep.b_max_c,
+                depth=jnp.zeros(rep.b_max_c.shape[0], jnp.int32),
+            )
+            mask_s = jnp.ones((S,), bool)
+            slice_hi, _ = waterfill_jax(
+                rep.slice_lo,
+                mask_s,
+                forest,
+                jnp.clip(slice_demand, rep.slice_lo, rep.slice_umax),
+            )
+            slice_hi, _ = waterfill_jax(slice_hi, mask_s, forest, rep.slice_umax)
+            lo_ext = jnp.concatenate([rep.slice_lo, jnp.zeros((1,), dt)])
+            hi_ext = jnp.concatenate([slice_hi, jnp.full((1,), jnp.inf, dt)])
+            sla_lo = jnp.maximum(rowmap.lo_local, lo_ext[rowmap.slice_idx])
+            sla_hi = jnp.minimum(rowmap.hi_local, hi_ext[rowmap.slice_idx])
+            slice_hi_out = slice_hi
+        elif rowmap is not None:
+            sla_lo, sla_hi = rowmap.lo_local, rowmap.hi_local
+            slice_hi_out = rep.slice_lo
+        else:
+            sla_lo = jnp.zeros((k_loc, 0), dt)
+            sla_hi = jnp.zeros((k_loc, 0), dt)
+            slice_hi_out = rep.slice_lo
+
+        # -- broadcast leg: every shard consumes its own domains' feeds ----
+        grants_loc = lax.dynamic_slice_in_dim(grants, idx * k_loc, k_loc)
+        cap_step = cap.at[:, 0].set(grants_loc)
+
+    with jax.named_scope("domains"):
+        x1, x2, x3, wcarry, stats, new_inc, new_rec = _solve_domains(
+            dom, cap_step, sla_lo, sla_hi, r, active, warm, carry, rec,
+            meta=meta, opts=opts, rec_cfg=rec_cfg,
         )
-        mask_s = jnp.ones((S,), bool)
-        slice_hi, _ = waterfill_jax(
-            rep.slice_lo,
-            mask_s,
-            forest,
-            jnp.clip(slice_demand, rep.slice_lo, rep.slice_umax),
-        )
-        slice_hi, _ = waterfill_jax(slice_hi, mask_s, forest, rep.slice_umax)
-        lo_ext = jnp.concatenate([rep.slice_lo, jnp.zeros((1,), dt)])
-        hi_ext = jnp.concatenate([slice_hi, jnp.full((1,), jnp.inf, dt)])
-        sla_lo = jnp.maximum(rowmap.lo_local, lo_ext[rowmap.slice_idx])
-        sla_hi = jnp.minimum(rowmap.hi_local, hi_ext[rowmap.slice_idx])
-        slice_hi_out = slice_hi
-    elif rowmap is not None:
-        sla_lo, sla_hi = rowmap.lo_local, rowmap.hi_local
-        slice_hi_out = rep.slice_lo
-    else:
-        sla_lo = jnp.zeros((k_loc, 0), dt)
-        sla_hi = jnp.zeros((k_loc, 0), dt)
-        slice_hi_out = rep.slice_lo
-
-    # -- broadcast leg: every shard consumes its own domains' feeds --------
-    grants_loc = lax.dynamic_slice_in_dim(grants, idx * k_loc, k_loc)
-    cap_step = cap.at[:, 0].set(grants_loc)
-
-    _, _, x3, wcarry, stats, new_inc, new_rec = _solve_domains(
-        dom, cap_step, sla_lo, sla_hi, r, active, warm, carry, rec,
-        meta=meta, opts=opts, rec_cfg=rec_cfg,
-    )
     # per-shard incremental dispatch: each shard's all-skip cond branches
     # independently inside _solve_domains (no collectives on either side);
     # recording is shard-local too — each shard appends its own lanes
-    return (
-        x3, wcarry, stats, new_inc, grants, demand,
-        rep.slice_lo, slice_hi_out, new_rec,
+    return StepOut(
+        x1, x2, x3, wcarry, stats, new_inc, grants, demand,
+        rep.slice_lo, slice_hi_out, new_rec, rounds,
     )
 
 
@@ -243,16 +274,19 @@ def _step_jit(
             rep_spec,
             sharded,
         ),
-        out_specs=(
-            sharded,
-            sharded,
-            sharded,
-            sharded,
-            rep_spec,
-            rep_spec,
-            rep_spec,
-            rep_spec,
-            sharded,
+        out_specs=StepOut(
+            x1=sharded,
+            x2=sharded,
+            x3=sharded,
+            warm=sharded,
+            stats=sharded,
+            carry=sharded,
+            grants=rep_spec,
+            demand=rep_spec,
+            slice_lo=rep_spec,
+            slice_hi=rep_spec,
+            rec=sharded,
+            coordinator_rounds=rep_spec,
         ),
     )
     return fn(dom, cap, r, active, rowmap, warm, carry, rep, rec)
@@ -268,7 +302,11 @@ def step(
     incremental certify anchor with domain-sharded ``[K, ...]`` leaves (None
     outside incremental mode); ``rec`` is the domain-sharded
     :class:`repro.obs.recorder.RecorderState` batch (None when recording is
-    off)."""
+    off).  Returns a :class:`StepOut`.
+
+    The program's named scopes split its device time: ``coordinator``
+    (local demand reduction, the ``psum``, the replicated plan and the
+    grants' broadcast) and ``domains`` (the vmapped per-domain solves)."""
     if coord_mode not in ("waterfill", "subtree"):
         raise ValueError(
             f"sharded dispatch supports waterfill/subtree coordinators, "

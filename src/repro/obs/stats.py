@@ -45,6 +45,16 @@ class StepStats(dict):
     device time scales with, and the tree levels whose search ran, 0 where
     no node binds).  Values are Python scalars on the engine path and numpy
     arrays on batched/fleet paths — the record is shape-agnostic on purpose.
+
+    Extras by path: the fleet's sharded dispatch adds
+    ``coordinator_rounds`` (an ``int``: the rounds of the coordinator
+    plan's two ``waterfill_jax`` grant passes, demand then headroom,
+    computed replicated on every shard); the fleet adds ``slice_lo`` and
+    ``slice_hi`` with cross-cut tenants; the engine adds ``iter_budget``.
+    :class:`repro.fleet.FleetOrchestrator` ``history`` rows carry
+    ``coordinator_rounds`` where the stats have it, and every mode's rows
+    carry ``iterations_max`` and ``iterations_min``, the slowest and the
+    fastest domain's iterations (the lockstep's straggler).
     """
 
     @classmethod

@@ -21,6 +21,7 @@ import subprocess
 import sys
 
 import numpy as np
+import pytest
 
 from repro.core import engine as engine_mod
 from repro.core.nvpax import NvpaxOptions
@@ -124,18 +125,39 @@ for t in range(2, 4):
     rs = stacked.step(teles[t])
     rh = sharded.step(teles[t])
     parity = max(parity, float(np.max(np.abs(rh.allocation - rs.allocation))))
+retraces = sharded_mod.trace_count() - s0
+
+# two domains per shard, as on a four-chip mesh of eight halls
+pdn2 = homogeneous_fleet(
+    16, racks_per_domain=1, servers_per_rack=2, gpus_per_server=4,
+    domain_oversub=0.9, root_oversub=1.0,
+)
+stacked2 = FleetOrchestrator(pdn2, level=1, mode="stacked")
+sharded2 = FleetOrchestrator(pdn2, level=1, mode="sharded")
+phase_parity = 0.0
+for t in range(3):
+    tele = rng.uniform(60, 690, pdn2.n)
+    rs = stacked2.step(tele)
+    rh = sharded2.step(tele)
+    for name in ("phase1", "phase2", "allocation"):
+        gap = np.max(np.abs(getattr(rh, name) - getattr(rs, name)))
+        phase_parity = max(phase_parity, float(gap))
 print(json.dumps({
     "mesh_devices": sharded_mod.shard_count(sharded.k),
     "parity_W": parity,
-    "retraces_after_warmup": sharded_mod.trace_count() - s0,
+    "retraces_after_warmup": retraces,
+    "two_per_shard": {
+        "domains": sharded2.k,
+        "mesh_devices": sharded_mod.shard_count(sharded2.k),
+        "phase_parity_W": phase_parity,
+    },
 }))
 """
 
 
-def test_sharded_forced_multidevice_subprocess():
-    """The real multi-shard path: 8 forced host devices, one domain per
-    shard, cross-shard psum + replicated waterfill.  Parity and the
-    zero-recompile contract must hold exactly as on the 1-device mesh."""
+@pytest.fixture(scope="module")
+def multidevice():
+    """One subprocess on 8 forced host devices runs every multi-shard case."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     src = os.path.join(os.path.dirname(__file__), "..", "src")
@@ -148,7 +170,22 @@ def test_sharded_forced_multidevice_subprocess():
         timeout=900,
     )
     assert proc.returncode == 0, proc.stderr[-2000:]
-    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+def test_sharded_forced_multidevice_subprocess(multidevice):
+    """The real multi-shard path: 8 forced host devices, one domain per
+    shard, cross-shard psum + replicated waterfill.  Parity and the
+    zero-recompile contract must hold exactly as on the 1-device mesh."""
+    out = multidevice
     assert out["mesh_devices"] == 8  # one domain per mesh device
     assert out["parity_W"] <= 1e-6
     assert out["retraces_after_warmup"] == 0
+
+
+def test_sharded_two_domains_per_shard_phases_match_stacked(multidevice):
+    """Two domains per shard, vmapped in lockstep on each: the per-phase
+    caps equal the stacked dispatch's to 1e-6 W."""
+    out = multidevice["two_per_shard"]
+    assert (out["domains"], out["mesh_devices"]) == (16, 8)
+    assert out["phase_parity_W"] <= 1e-6

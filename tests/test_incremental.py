@@ -53,6 +53,29 @@ def test_certify_full_skip_on_identical_step():
     np.testing.assert_array_equal(res2.allocation, res.allocation)
 
 
+@pytest.mark.parametrize("path", ["optimize", "engine"])
+def test_full_skip_returns_the_anchor_phases(path):
+    """A skipped step returns the anchor solve's Phase I and Phase II caps,
+    not its final caps twice: idle devices make Phase II and the final caps
+    differ."""
+    pdn = small_pdn()
+    tele = np.random.default_rng(5).uniform(250, 450, pdn.n)
+    tele[pdn.n // 2 :] = 50.0  # an idle rack: Phase III raises it
+    if path == "optimize":
+        ap = AllocProblem.build(pdn, tele)
+        first = optimize(ap, TIGHT_INC)
+        again = optimize(ap, TIGHT_INC, warm=first.warm_state, carry=first.carry)
+    else:
+        eng = AllocEngine(pdn, options=TIGHT_INC)
+        first, again = eng.step(tele), eng.step(tele)
+    assert again.stats["skipped"] and not first.stats["skipped"]
+    assert np.max(first.allocation - first.phase2) > 1.0
+    for name in ("phase1", "phase2", "allocation"):
+        np.testing.assert_allclose(
+            getattr(again, name), getattr(first, name), rtol=0, atol=1e-9
+        )
+
+
 def test_certify_rejects_demand_move():
     # the max-min phases hand out surplus as base-relative increments, so
     # ANY demand move must force a re-solve — even on a device that holds

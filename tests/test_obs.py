@@ -396,6 +396,115 @@ def test_engine_spans_share_the_profiler_host_line(small_pdn, tmp_path):
         assert a <= s <= e <= b
 
 
+FLEET_SPANS = [
+    "fleet.dispatch/fleet.scatter",
+    "fleet.dispatch/fleet.plan",
+    "fleet.dispatch/fleet.upload",
+    "fleet.dispatch/fleet.wait",
+    "fleet.dispatch/fleet.fetch",
+    "fleet.dispatch/fleet.stats",
+]
+
+
+def test_sharded_fleet_spans_nest_in_order(small_pdn):
+    orch = FleetOrchestrator(small_pdn, level=1, mode="sharded")
+    p = _powers(small_pdn.n, 1, seed=41)[0]
+    orch.step(p)  # compile the cold and the warm-carry programs
+    orch.step(p)
+    spans.reset()
+    spans.enable()
+    try:
+        res = orch.step(p)
+        recs = spans.drain()
+    finally:
+        spans.disable()
+    assert [r["span"] for r in recs] == FLEET_SPANS + ["fleet.dispatch"]
+    outer = recs[-1]
+    t_end = outer["t0"] + outer["ms"] / 1e3
+    for a, b in zip(recs[:-2], recs[1:-1]):
+        assert outer["t0"] <= a["t0"] <= a["t0"] + a["ms"] / 1e3 <= b["t0"] <= t_end
+    # wall_time_s covers the dispatch, from fleet.scatter into fleet.stats
+    assert recs[-2]["t0"] - recs[0]["t0"] <= res.wall_time_s
+
+
+def test_disabled_fleet_spans_open_no_annotation(small_pdn, monkeypatch):
+    opened = []
+
+    class Annotation:
+        def __init__(self, name):
+            opened.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(spans.jax.profiler, "TraceAnnotation", Annotation)
+    orch = FleetOrchestrator(small_pdn, level=1, mode="sharded")
+    p = _powers(small_pdn.n, 1, seed=43)[0]
+    spans.reset()
+    orch.step(p)
+    assert spans.drain() == [] and opened == []
+    spans.enable()
+    try:
+        orch.step(p)
+    finally:
+        spans.disable()
+    assert opened == ["fleet.dispatch"] + FLEET_SPANS
+    assert len(spans.drain()) == len(opened)
+
+
+def test_sharded_fleet_answers_do_not_depend_on_spans(small_pdn):
+    on, off = (FleetOrchestrator(small_pdn, level=1, mode="sharded") for _ in "ab")
+    for p in _powers(small_pdn.n, 2, seed=47):
+        spans.enable()
+        try:
+            a = on.step(p)
+        finally:
+            spans.disable()
+            spans.reset()
+        b = off.step(p)
+        for name in ("allocation", "phase1", "phase2", "grants", "demand"):
+            np.testing.assert_array_equal(getattr(a, name), getattr(b, name))
+        assert a.stats["coordinator_rounds"] == b.stats["coordinator_rounds"]
+
+
+def test_coordinator_rounds_count_both_grant_passes():
+    """``coordinator_rounds`` is the rounds of the plan's demand pass plus
+    its headroom pass, as direct ``waterfill_jax`` calls on the step's own
+    demand count them; the history carries it with the per-domain
+    iteration extremes."""
+    import jax
+
+    from repro.core.treeops import TreeTopo
+    from repro.core.waterfill import waterfill_jax
+
+    pdn = homogeneous_fleet(4, root_oversub=0.8)  # a feed the grants share
+    orch = FleetOrchestrator(pdn, level=1, mode="sharded")
+    res = orch.step(_powers(pdn.n, 1, seed=53, lo=300.0, hi=700.0)[0])
+    dcap, ccap, dmin = orch._effective_domain_caps()
+    with jax.enable_x64(True):
+        ctree = TreeTopo(
+            start=jnp.asarray(orch.coordinator.start),
+            end=jnp.asarray(orch.coordinator.end),
+            cap=jnp.asarray(ccap),
+            depth=jnp.zeros(ccap.shape[0], jnp.int32),
+        )
+        mask = jnp.ones(orch.k, bool)
+        want = jnp.clip(jnp.asarray(res.demand), dmin, dcap)
+        grants, demand_rounds = waterfill_jax(jnp.asarray(dmin), mask, ctree, want)
+        grants, headroom_rounds = waterfill_jax(grants, mask, ctree, jnp.asarray(dcap))
+    rounds = int(demand_rounds) + int(headroom_rounds)
+    assert int(demand_rounds) > 0 and int(headroom_rounds) > 0
+    assert res.stats["coordinator_rounds"] == rounds
+    np.testing.assert_allclose(res.grants, np.asarray(grants), rtol=0, atol=1e-9)
+    row = orch.history[-1]
+    iters = np.asarray(res.stats["iterations"])
+    assert row["coordinator_rounds"] == rounds
+    assert (row["iterations_max"], row["iterations_min"]) == (iters.max(), iters.min())
+
+
 # -- StepStats consolidation ----------------------------------------------
 
 

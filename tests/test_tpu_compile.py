@@ -86,3 +86,36 @@ def test_pdhg_kernel_compiles_for_v5e(kernel, one_chip):
         fn, args = dual_prox, (vec,) * 5
     compiled = fn.lower(*args, interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_sharded_fleet_step_compiles_for_a_v5e_mesh(topo):
+    """The float64 cold step of the sharded fleet dispatch on a 2x2 mesh,
+    two domains a chip: the coordinator's ``psum`` must reach the compiled
+    program as a collective, and the caps stay sharded over the domains."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.fleet import FleetOrchestrator
+    from repro.fleet import sharded as shd
+    from repro.pdn.hierarchy_gen import homogeneous_fleet
+
+    mesh = Mesh(np.array(topo.devices), ("domains",))
+    split, whole = NamedSharding(mesh, P("domains")), NamedSharding(mesh, P())
+    pdn = homogeneous_fleet(8, racks_per_domain=1, servers_per_rack=2, gpus_per_server=4)
+    orch = FleetOrchestrator(pdn, level=1, mode="stacked")  # host mirrors only
+    k, n, m = orch.k, orch._N, orch._M
+    with jax.enable_x64(True):
+        rep, rowmap = orch._sharded_plan()
+        compiled = shd._step_jit.lower(
+            _shapes(orch._dom, split),
+            jax.ShapeDtypeStruct((k, m), jnp.float64, sharding=split),
+            jax.ShapeDtypeStruct((k, n), jnp.float64, sharding=split),
+            jax.ShapeDtypeStruct((k, n), jnp.bool_, sharding=split),
+            rowmap, None, None, _shapes(rep, whole), None,
+            mesh=mesh, meta=orch.meta, opts=orch.options.solver,
+            coord_mode="waterfill", rec_cfg=None,
+        ).compile()
+    text = compiled.as_text()
+    assert "all-gather" in text or "all-reduce" in text
+    x1, x2, x3 = compiled.output_shardings[:3]
+    assert x1 == x2 == x3 == split
