@@ -22,8 +22,10 @@ This module re-expresses the same algorithm as a fixed-shape jax program:
   evaluated as traced predicates;
 * the exact feasibility repair is the shared fixed-trip
   ``phases.repair(..., n_depths)`` fori-loop;
-* the SLA-free max-min fast path is the trace-safe level-wise tree
-  projection :func:`repro.core.waterfill.waterfill_project_jax`.
+* on SLA-free problems each Phase I level QP is the exact weighted tree
+  projection :func:`repro.core.waterfill.tree_project_jax`, and the
+  max-min fast path is the level-wise tree projection
+  :func:`repro.core.waterfill.waterfill_project_jax`.
 
 Because every step-problem builder (``qp_step``, ``lp_step``,
 ``saturated_mask``, ``repair``) is imported from :mod:`repro.core.phases`,
@@ -53,7 +55,7 @@ from repro.core import phases, solver
 from repro.core.nvpax import NvpaxOptions
 from repro.core.problem import AllocProblem
 from repro.core.solver.options import KKT_HIST_BUCKETS
-from repro.core.waterfill import waterfill_project_jax
+from repro.core.waterfill import tree_project_jax, waterfill_project_jax
 from repro.obs import recorder as obs_recorder
 from repro.obs.stats import StepStats
 
@@ -107,8 +109,11 @@ class BatchedStepState(NamedTuple):
     kkt_res: jnp.ndarray  # dtype scalar
     restarts: jnp.ndarray  # int32
     kkt_hist: jnp.ndarray  # [KKT_HIST_BUCKETS] int32
-    waterfill_rounds: jnp.ndarray  # int32: search steps of the max-min fill
-    waterfill_levels: jnp.ndarray  # int32: tree levels whose search ran
+    # search steps of the tree projection (Phase I) or the max-min fill
+    # (Phases II/III) on problems with no tenant rows, and the tree levels
+    # whose search ran
+    search_steps: jnp.ndarray  # int32
+    search_levels: jnp.ndarray  # int32
 
 
 @dataclass
@@ -219,11 +224,13 @@ def _phase1_scan(
         kkt_res=jnp.zeros((), ap.l.dtype),
         restarts=jnp.zeros((), jnp.int32),
         kkt_hist=jnp.zeros((KKT_HIST_BUCKETS,), jnp.int32),
-        waterfill_rounds=jnp.zeros((), jnp.int32),
-        waterfill_levels=jnp.zeros((), jnp.int32),
+        search_steps=jnp.zeros((), jnp.int32),
+        search_levels=jnp.zeros((), jnp.int32),
     )
     if not meta.levels:
         return init
+
+    project = meta.use_waterfill and ap.sla.k == 0
 
     def level_step(st: BatchedStepState, p):
         mask_a = ap.active & (ap.priority == p)
@@ -232,6 +239,19 @@ def _phase1_scan(
             prob = phases.qp_step(
                 ap, st.x, mask_a, st.mask, meta.eps, pin_free=meta.pin_free
             )
+            if project:
+                # no tenant rows: the level QP is a tree projection, solved
+                # exactly; the solver state passes through untouched
+                x, steps, levels = tree_project_jax(
+                    prob.target, prob.w, prob.lo, prob.hi, ap.tree, meta.n_depths
+                )
+                return st._replace(
+                    x=phases.repair(x, ap, meta.n_depths),
+                    mask=st.mask | mask_a,
+                    solves=st.solves + 1,
+                    search_steps=st.search_steps + steps,
+                    search_levels=st.search_levels + levels,
+                )
             sol = solver.SolverState(
                 st.x, st.solver.t, st.solver.y_tree, st.solver.y_sla, st.solver.y_imp
             )
@@ -252,8 +272,8 @@ def _phase1_scan(
                 kkt_res=jnp.maximum(st.kkt_res, res),
                 restarts=st.restarts + stats.restarts,
                 kkt_hist=st.kkt_hist + stats.score_hist,
-                waterfill_rounds=st.waterfill_rounds,
-                waterfill_levels=st.waterfill_levels,
+                search_steps=st.search_steps,
+                search_levels=st.search_levels,
             )
 
         # the host driver only sweeps levels present among this scenario's
@@ -313,8 +333,8 @@ def _maxmin_loop(
             kkt_res=jnp.zeros((), dtype),
             restarts=jnp.zeros((), jnp.int32),
             kkt_hist=jnp.zeros((KKT_HIST_BUCKETS,), jnp.int32),
-            waterfill_rounds=steps,
-            waterfill_levels=levels,
+            search_steps=steps,
+            search_levels=levels,
         )
 
     # freeze devices with no slack at entry (see phases.run_maxmin_phase)
@@ -331,8 +351,8 @@ def _maxmin_loop(
         kkt_res=jnp.zeros((), dtype),
         restarts=jnp.zeros((), jnp.int32),
         kkt_hist=jnp.zeros((KKT_HIST_BUCKETS,), jnp.int32),
-        waterfill_rounds=jnp.zeros((), jnp.int32),
-        waterfill_levels=jnp.zeros((), jnp.int32),
+        search_steps=jnp.zeros((), jnp.int32),
+        search_levels=jnp.zeros((), jnp.int32),
     )
 
     def cond(st: BatchedStepState):
@@ -379,8 +399,8 @@ def _maxmin_loop(
             kkt_res=jnp.maximum(st.kkt_res, res),
             restarts=st.restarts + stats.restarts,
             kkt_hist=st.kkt_hist + stats.score_hist,
-            waterfill_rounds=st.waterfill_rounds,
-            waterfill_levels=st.waterfill_levels,
+            search_steps=st.search_steps,
+            search_levels=st.search_levels,
         )
 
     return lax.while_loop(cond, body, init)
@@ -469,8 +489,8 @@ def solve_three_phase(
             kkt_res=jnp.zeros((), dtype),
             restarts=jnp.zeros((), jnp.int32),
             kkt_hist=jnp.zeros((KKT_HIST_BUCKETS,), jnp.int32),
-            waterfill_rounds=jnp.zeros((), jnp.int32),
-            waterfill_levels=jnp.zeros((), jnp.int32),
+            search_steps=jnp.zeros((), jnp.int32),
+            search_levels=jnp.zeros((), jnp.int32),
         )
 
     def refine(x, sol, opt_set, free_set, iters_before):
@@ -513,8 +533,8 @@ def solve_three_phase(
                          kkt_res=jnp.zeros((), dtype),
                          restarts=jnp.zeros((), jnp.int32),
                          kkt_hist=jnp.zeros((KKT_HIST_BUCKETS,), jnp.int32),
-                         waterfill_rounds=jnp.zeros((), jnp.int32),
-                         waterfill_levels=jnp.zeros((), jnp.int32))
+                         search_steps=jnp.zeros((), jnp.int32),
+                         search_levels=jnp.zeros((), jnp.int32))
         x2 = x1
 
     w3 = phases.merge_warm(p2.solver, warm.p3 if warm is not None else None)
@@ -536,8 +556,8 @@ def solve_three_phase(
                          kkt_res=jnp.zeros((), dtype),
                          restarts=jnp.zeros((), jnp.int32),
                          kkt_hist=jnp.zeros((KKT_HIST_BUCKETS,), jnp.int32),
-                         waterfill_rounds=jnp.zeros((), jnp.int32),
-                         waterfill_levels=jnp.zeros((), jnp.int32))
+                         search_steps=jnp.zeros((), jnp.int32),
+                         search_levels=jnp.zeros((), jnp.int32))
         x3 = x2
 
     stats = {
@@ -551,10 +571,14 @@ def solve_three_phase(
         "iterations_p3": p3.iterations,
         # search steps of the max-min fill and the tree levels whose search
         # ran (the SLA-free Phase II/III path, which runs no PDHG iteration)
-        "waterfill_rounds_p2": p2.waterfill_rounds,
-        "waterfill_rounds_p3": p3.waterfill_rounds,
-        "waterfill_levels_p2": p2.waterfill_levels,
-        "waterfill_levels_p3": p3.waterfill_levels,
+        # search steps of Phase I's tree projection and the tree levels
+        # whose search ran (the SLA-free path, which runs no PDHG iteration)
+        "project_steps_p1": p1.search_steps,
+        "project_levels_p1": p1.search_levels,
+        "waterfill_rounds_p2": p2.search_steps,
+        "waterfill_rounds_p3": p3.search_steps,
+        "waterfill_levels_p2": p2.search_levels,
+        "waterfill_levels_p3": p3.search_levels,
         "converged": p1.converged & p2.converged & p3.converged,
         "kkt_certified": p1.certified & p2.certified & p3.certified,
         "truncated": truncated,
@@ -702,6 +726,8 @@ def _solve_batched(
             "iterations_p1": zi,
             "iterations_p2": zi,
             "iterations_p3": zi,
+            "project_steps_p1": zi,
+            "project_levels_p1": zi,
             "waterfill_rounds_p2": zi,
             "waterfill_rounds_p3": zi,
             "waterfill_levels_p2": zi,

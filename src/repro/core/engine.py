@@ -561,6 +561,8 @@ class AllocEngine:
                         "phase_iterations": step_stats["phase_iterations"],
                         "waterfill_rounds": step_stats["waterfill_rounds"],
                         "waterfill_levels": step_stats["waterfill_levels"],
+                        "project_steps_p1": step_stats["project_steps_p1"],
+                        "project_levels_p1": step_stats["project_levels_p1"],
                         "truncated": step_stats["truncated"],
                         "skipped": step_stats["skipped"],
                     }

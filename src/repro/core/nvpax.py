@@ -35,8 +35,9 @@ class NvpaxOptions:
     run_phase3: bool = True
     max_rounds: int = phases.MAX_ROUNDS
     x64: bool = True  # solve in float64 (jax.enable_x64 context)
-    # exact water-filling fast path for the max-min phases on SLA-free
-    # problems (beyond-paper optimization; equals the iterated-LP limit)
+    # exact fast paths on SLA-free problems (beyond-paper optimization):
+    # Phase I's level QPs as a tree projection, and the max-min phases as
+    # water-filling (equals the iterated-LP limit)
     use_waterfill: bool = True
     # Anytime / deadline-aware mode (the paper's stated future work,
     # section 6): every phase boundary is a valid, feasible allocation, so
@@ -154,7 +155,8 @@ def optimize(
             state = w1._replace(x=x1)
         else:
             x1, state, s1 = phases.phase1(
-                ap, options.solver, options.eps, warm.p1 if warm else None
+                ap, options.solver, options.eps, warm.p1 if warm else None,
+                use_waterfill=options.use_waterfill,
             )
         carry1 = state
         x2 = x1

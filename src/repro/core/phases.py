@@ -19,6 +19,7 @@ from jax import lax
 from repro.core import solver
 from repro.core.problem import INF, AllocProblem, StepProblem
 from repro.core.treeops import sla_matvec, sla_rmatvec, tree_matvec, tree_rmatvec
+from repro.core.waterfill import tree_project_jax
 
 __all__ = [
     "PhaseStats",
@@ -265,13 +266,23 @@ def lp_step(
 # ---------------------------------------------------------------------------
 
 
+_tree_project = jax.jit(tree_project_jax, static_argnames="n_depths")
+
+
 def phase1(
     ap: AllocProblem,
     opts: solver.SolverOptions,
     eps: float = 1e-5,
     warm: solver.SolverState | None = None,
+    use_waterfill: bool = True,
 ) -> tuple[jnp.ndarray, solver.SolverState, PhaseStats]:
-    """Algorithm 1: priority-ordered request satisfaction."""
+    """Algorithm 1: priority-ordered request satisfaction.
+
+    When no tenant SLAs are present (``use_waterfill=True``) each level QP
+    is a weighted projection onto the box and the tree's caps, solved
+    exactly by :func:`repro.core.waterfill.tree_project_jax`; the solver
+    state passes through untouched.  With SLAs the PDHG solve is required.
+    """
     n, m, k = ap.n, ap.tree.m, ap.sla.k
     dtype = ap.l.dtype
     state = warm if warm is not None else solver.SolverState.zeros(n, m, k, dtype)
@@ -287,9 +298,18 @@ def phase1(
     solves = iters = 0
     conv = cert = True
     maxres = 0.0
+    project = use_waterfill and k == 0
     for p in levels:
         mask_a = ap.active & (ap.priority == p)
         prob = qp_step(ap, x, mask_a, finalized, eps, pin_free=pin_free)
+        if project:
+            x, _, _ = _tree_project(
+                prob.target, prob.w, prob.lo, prob.hi, ap.tree, n_depths
+            )
+            x = repair(x, ap, n_depths)
+            finalized = finalized | mask_a
+            solves += 1
+            continue
         state = solver.SolverState(x, state.t, state.y_tree, state.y_sla, state.y_imp)
         state, stats = solver.solve(prob, ap.tree, ap.sla, state, opts)
         x = repair(state.x, ap, n_depths)

@@ -21,6 +21,12 @@ Three fills share these semantics:
   devices that saturate: the jitted step's Phases II/III
   (:func:`repro.core.batched.solve_three_phase`, so ``AllocEngine`` and
   the fleet orchestrator's domain solves).
+
+Phase I shares the fills' level-wise search: :func:`tree_project_jax` is the
+weighted least-squares projection of the requests onto the box and the
+caps, Phase I's level QP solved exactly where no tenant rows exist
+(:func:`repro.core.batched.solve_three_phase` and the host
+:func:`repro.core.phases.phase1`).
 """
 
 from __future__ import annotations
@@ -29,7 +35,13 @@ import numpy as np
 
 from repro.pdn.tree import FlatPDN
 
-__all__ = ["waterfill", "waterfill_arrays", "waterfill_jax", "waterfill_project_jax"]
+__all__ = [
+    "tree_project_jax",
+    "waterfill",
+    "waterfill_arrays",
+    "waterfill_jax",
+    "waterfill_project_jax",
+]
 
 
 def waterfill_arrays(
@@ -245,6 +257,111 @@ def waterfill_project_jax(base, opt_mask, tree, u, n_depths: int):
 
         h, steps, levels = lax.fori_loop(0, n_depths, level, (h0, zero, zero))
         x = jnp.where(h > 0, jnp.minimum(x0 + h, u), x0)
+    return x, steps, levels
+
+
+def tree_project_jax(target, w, lo, hi, tree, n_depths: int):
+    """Weighted least-squares projection of ``target`` onto the box
+    ``[lo, hi]`` and the tree's caps, trace-safe under jit and vmap: Phase
+    I's level QP (paper eq. 4) solved exactly on problems with no tenant
+    rows.
+
+    It minimises ``sum(w_i * (x_i - target_i)**2)``.  At a node price ``P``
+    a device answers ``clip(target_i - P / w_i, lo_i, hi_i)``, and a device
+    carries the largest price of any node above it.  The prices are found
+    bottom-up, one tree level at a time: a node binds where its devices'
+    answers at the deeper levels' prices overshoot its cap, and its price is
+    the least ``P`` whose answers fit, found by a bracketing search over
+    ``SEARCH_CANDIDATES`` prices a step, vectorised over the nodes of a tree
+    level, until no bracket shrinks in the dtype.  The feasible (higher
+    price) end is kept, so every subtree sum stays at or below its cap up to
+    rounding; a node over its cap with every device at ``lo`` leaves them at
+    ``lo`` (the caller's repair does the rest).  Devices with ``w_i == 0``
+    are pinned (``lo == hi``).  A tree level where no node binds runs no
+    search step.
+
+    ``n_depths`` (static) is the number of tree levels, root included.
+    Returns ``(x, steps, levels)``: the projection, the search steps run
+    (int32; what its time scales with) and the tree levels whose search
+    ran.  Runs under the ``project`` named scope.
+    """
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from repro.core.treeops import tree_matvec, tree_rmatvec
+
+    target = jnp.asarray(target)
+    dtype = target.dtype
+    n, m = target.shape[0], tree.m
+    w, lo, hi = (jnp.asarray(a, dtype) for a in (w, lo, hi))
+    zero = jnp.zeros((), jnp.int32)
+    frac = jnp.arange(1, SEARCH_CANDIDATES + 1, dtype=dtype) / (SEARCH_CANDIDATES + 1)
+    pad = jnp.zeros((SEARCH_CANDIDATES, 1), dtype)
+    inf = jnp.asarray(jnp.inf, dtype)
+    weighted = w > 0
+    inv_w = jnp.where(weighted, 1.0 / jnp.where(weighted, w, 1.0), 0.0)
+
+    def answer(price):
+        """Each device's value at a price (``+inf`` puts it at ``lo``)."""
+        return jnp.where(weighted, jnp.clip(target - price * inv_w, lo, hi), lo)
+
+    with jax.named_scope("project"):
+        x0 = answer(jnp.zeros((), dtype))
+        # a node whose devices all fit at their upper bounds never binds
+        can_bind = tree_matvec(jnp.maximum(hi, lo), tree) > tree.cap
+        room = tree_matvec(lo, tree) < tree.cap
+        # above every price that moves a device: at 2 * p_top each weighted
+        # device answers lo
+        p_top = 2.0 * jnp.max(jnp.where(weighted, w * (target - lo), 0.0), initial=0.0)
+        # anc[d, i]: the depth-d node above device i, or m (the slot past
+        # the last node) where none is; exact, as an integer prefix sum
+        ids = jnp.where(
+            tree.depth[None, :] == jnp.arange(n_depths, dtype=jnp.int32)[:, None],
+            jnp.arange(1, m + 1, dtype=jnp.int32),
+            0,
+        )
+        anc = jax.vmap(lambda y: tree_rmatvec(y, tree, n))(ids) - 1
+        anc = jnp.where(anc < 0, m, anc)
+
+        def level(k, carry):
+            x, steps, levels = carry
+            d = n_depths - 1 - k  # deepest level first
+            above = anc[d]
+            bind = (tree.depth == d) & can_bind & (tree_matvec(x, tree) > tree.cap)
+            # bracket [p_lo, p_hi]: p_hi fits under the cap, p_lo overshoots
+            p_lo = jnp.zeros((m,), dtype)
+            p_hi = jnp.where(bind & room, p_top, 0.0)
+
+            def cond(c):
+                _, _, live, s = c
+                return jnp.any(live) & (s < SEARCH_STEP_CAP)
+
+            def body(c):
+                p_lo, p_hi, live, s = c
+                cand = p_lo + (p_hi - p_lo) * frac[:, None]  # [SEARCH_CANDIDATES, m]
+                price = jnp.concatenate([cand, pad], axis=1)[:, above]
+                fill = jnp.minimum(answer(price), x)
+                fits = jax.vmap(lambda v: tree_matvec(v, tree))(fill) <= tree.cap
+                hi_new = jnp.minimum(p_hi, jnp.min(jnp.where(fits, cand, inf), axis=0))
+                lo_new = jnp.maximum(p_lo, jnp.max(jnp.where(fits, -inf, cand), axis=0))
+                lo_new = jnp.minimum(lo_new, hi_new)  # rounding may cross them
+                shrank = (lo_new != p_lo) | (hi_new != p_hi)
+                return (
+                    jnp.where(live, lo_new, p_lo),
+                    jnp.where(live, hi_new, p_hi),
+                    live & shrank & (hi_new > lo_new),
+                    s + 1,
+                )
+
+            init = (p_lo, p_hi, bind & room & (p_hi > p_lo), zero)
+            _, p, _, s = lax.while_loop(cond, body, init)
+            # a node that cannot fit even at lo prices its devices to lo
+            p = jnp.where(bind, jnp.where(room, p, inf), 0.0)
+            p = jnp.concatenate([p, jnp.zeros((1,), dtype)])
+            return jnp.minimum(x, answer(p[above])), steps + s, levels + (s > 0)
+
+        x, steps, levels = lax.fori_loop(0, n_depths, level, (x0, zero, zero))
     return x, steps, levels
 
 

@@ -247,6 +247,8 @@ def _solve_domains(
             "iterations_p1": zi,
             "iterations_p2": zi,
             "iterations_p3": zi,
+            "project_steps_p1": zi,
+            "project_levels_p1": zi,
             "waterfill_rounds_p2": zi,
             "waterfill_rounds_p3": zi,
             "waterfill_levels_p2": zi,
@@ -1411,6 +1413,7 @@ class FleetOrchestrator:
         allocs, phase1, phase2 = [], [], []
         solves, iters, phase_iters, conv = [], [], [], []
         skipped, certify, wf_rounds, wf_levels = [], [], [], []
+        pj_steps, pj_levels = [], []
         certified, truncated, kkt_res, restarts, kkt_hist = [], [], [], [], []
         for k, eng in enumerate(self._engines):
             rk = req[offs[k] : offs[k + 1]]
@@ -1435,6 +1438,8 @@ class FleetOrchestrator:
                 phase_iters.append([0, 0, 0])
                 wf_rounds.append([0, 0])
                 wf_levels.append([0, 0])
+                pj_steps.append(0)
+                pj_levels.append(0)
                 conv.append(True)
                 skipped.append(True)
                 certify.append(True)
@@ -1457,6 +1462,8 @@ class FleetOrchestrator:
             phase_iters.append(res.stats["phase_iterations"])
             wf_rounds.append(res.stats["waterfill_rounds"])
             wf_levels.append(res.stats["waterfill_levels"])
+            pj_steps.append(res.stats["project_steps_p1"])
+            pj_levels.append(res.stats["project_levels_p1"])
             conv.append(res.stats["converged"])
             skipped.append(bool(res.stats.get("skipped", False)))
             certify.append(bool(res.stats.get("certify_pass", False)))
@@ -1497,6 +1504,8 @@ class FleetOrchestrator:
             kkt_hist=np.stack(kkt_hist, axis=0),
             waterfill_rounds=np.asarray(wf_rounds),
             waterfill_levels=np.asarray(wf_levels),
+            project_steps_p1=np.asarray(pj_steps),
+            project_levels_p1=np.asarray(pj_levels),
             mode="loop",
         )
         return (

@@ -20,6 +20,7 @@ from __future__ import annotations
 
 from typing import Any
 
+import jax
 import numpy as np
 
 __all__ = ["StepStats"]
@@ -43,7 +44,9 @@ class StepStats(dict):
     ``[K, 2]``, Phases II and III, on the SLA-free path that runs no PDHG
     iteration: the sequential search steps of the max-min fill, which its
     device time scales with, and the tree levels whose search ran, 0 where
-    no node binds).  Values are Python scalars on the engine path and numpy
+    no node binds), and ``project_steps_p1`` and ``project_levels_p1`` (a
+    scalar or ``[K]``: the same two counts for Phase I's tree projection on
+    that path).  Values are Python scalars on the engine path and numpy
     arrays on batched/fleet paths — the record is shape-agnostic on purpose.
 
     Extras by path: the fleet's sharded dispatch adds
@@ -74,6 +77,8 @@ class StepStats(dict):
         kkt_hist: Any = None,
         waterfill_rounds: Any = None,
         waterfill_levels: Any = None,
+        project_steps_p1: Any = None,
+        project_levels_p1: Any = None,
         **extras: Any,
     ) -> "StepStats":
         out = cls()
@@ -91,6 +96,8 @@ class StepStats(dict):
             "kkt_hist": kkt_hist,
             "waterfill_rounds": waterfill_rounds,
             "waterfill_levels": waterfill_levels,
+            "project_steps_p1": project_steps_p1,
+            "project_levels_p1": project_levels_p1,
         }
         for name, value in fields.items():
             if value is None:
@@ -109,12 +116,15 @@ class StepStats(dict):
         """Convert the traced stats dict of
         :func:`repro.core.batched.solve_three_phase` (keys ``solves``,
         ``iterations``, ``iterations_p1..3``, ``waterfill_rounds_p2..3``,
-        ``waterfill_levels_p2..3``, flags) to host values.
+        ``waterfill_levels_p2..3``, ``project_steps_p1``,
+        ``project_levels_p1``, flags) to host values, fetched from the
+        device in one transfer.
 
         ``scalar=True`` is the engine (K=1) path: leaves become Python
         ``int``/``bool``/``float`` scalars, matching the pre-PR-8 engine
         stats dict exactly.
         """
+        stats = jax.device_get(dict(stats))
         pi = np.stack(
             [np.asarray(stats[f"iterations_p{i}"]) for i in (1, 2, 3)], axis=-1
         )
@@ -137,6 +147,8 @@ class StepStats(dict):
                 kkt_hist=np.asarray(stats["kkt_hist"]),
                 waterfill_rounds=[int(v) for v in wr],
                 waterfill_levels=[int(v) for v in wl],
+                project_steps_p1=int(stats["project_steps_p1"]),
+                project_levels_p1=int(stats["project_levels_p1"]),
                 **extras,
             )
         return cls.build(
@@ -153,6 +165,8 @@ class StepStats(dict):
             kkt_hist=np.asarray(stats["kkt_hist"]),
             waterfill_rounds=wr,
             waterfill_levels=wl,
+            project_steps_p1=np.asarray(stats["project_steps_p1"]),
+            project_levels_p1=np.asarray(stats["project_levels_p1"]),
             **extras,
         )
 

@@ -3,6 +3,7 @@ waterfill <-> iterated-LP equivalence."""
 
 from __future__ import annotations
 
+import functools
 import math
 
 import numpy as np
@@ -330,3 +331,246 @@ def test_engine_reports_no_waterfill_search_where_no_node_binds():
     stats, steps, levels, _ = _engine_step_and_direct_fills(pdn)
     assert stats["waterfill_rounds"] == steps == [0, 0]
     assert stats["waterfill_levels"] == levels == [0, 0]
+
+
+def _phase1_lanes(case):
+    """(problems, pin_free) of one Phase I case: problems share one tree;
+    more than one runs the lanes under one ``vmap``."""
+    from repro.pdn.tenants import assign_tenants
+
+    pdn = build_from_level_sizes([2, 3, 2], gpus_per_server=4)
+    rng = np.random.default_rng(21)
+    req = rng.uniform(400.0, 800.0, pdn.n)  # hot: the halls' caps bind
+    dtype = jnp.float32 if case == "float32" else jnp.float64
+    if case == "vmap_lanes":
+        # rack caps 4,760 W (binding above 595 W a device), hall caps
+        # 12,138 W (505.75 W), root 20,634.6 W (429.9 W): lanes bind at no
+        # level, the root, one rack, and the halls and the root
+        reqs = np.full((4, pdn.n), 240.0) + rng.uniform(-15.0, 15.0, (4, pdn.n))
+        reqs[1] += 210.0
+        reqs[2, :8] = 700.0
+        reqs[3] += 320.0
+        return [AllocProblem.build(pdn, r) for r in reqs], True
+    if case == "three_levels":
+        prio = assign_tenants(pdn, n_tenants=6, devices_per_tenant=8, seed=3).priority
+        return [AllocProblem.build(pdn, req, priority=prio, normalized=True)], True
+    ap = AllocProblem.build(pdn, req, normalized=case != "uniform_w", dtype=dtype)
+    if case == "inv_u_w":
+        u = rng.uniform(400.0, 900.0, pdn.n)
+        ap = ap._replace(
+            u=jnp.asarray(u), r=jnp.minimum(ap.r, u), weight_scale=jnp.asarray(1 / u)
+        )
+    if case in ("idle", "eps_l"):
+        active = rng.random(pdn.n) < 0.7
+        ap = AllocProblem.build(pdn, req + 150.0, active=active, normalized=True)
+    if case == "over_cap_at_entry":
+        # the first rack's cap below its eight devices' 1,600 W of floors
+        rack = int(np.flatnonzero(pdn.node_end - pdn.node_start == 8)[0])
+        ap = ap._replace(tree=ap.tree._replace(cap=ap.tree.cap.at[rack].set(1500.0)))
+    return [ap], case != "eps_l"
+
+
+def _active_set_qp(prob, tree, sla):
+    """A level QP solved by SLSQP, an active-set method, over
+    ``refsolve.dense_constraints``, then polished on its active set: it
+    lands on the active bounds, where ``ref_solve``'s interior point stops
+    inside them."""
+    import scipy.optimize as sopt
+
+    from repro.core.refsolve import dense_constraints
+
+    n = prob.n
+    w, t, lo, hi = (
+        np.asarray(a, np.float64) for a in (prob.w, prob.target, prob.lo, prob.hi)
+    )
+    w = w * (1e-2 / np.max(w))  # the scale at which SLSQP's line search ends cleanly
+    rows, _, row_hi = dense_constraints(tree, sla, n)
+    rows, keep = rows[:, :n], np.isfinite(row_hi)
+    res = sopt.minimize(
+        lambda v: 0.5 * np.sum(w * (v - t) ** 2),
+        x0=np.clip(t, lo, hi),
+        jac=lambda v: w * (v - t),
+        bounds=list(zip(lo, hi)),
+        constraints=[{
+            "type": "ineq",
+            "fun": lambda v: row_hi[keep] - rows[keep] @ v,
+            "jac": lambda v: -rows[keep],
+        }],
+        method="SLSQP",
+        options={"ftol": 1e-15, "maxiter": 1000},
+    )
+    assert res.success, res.message
+    # SLSQP stops on its objective, up to ~1e-4 W short in x: solve the
+    # KKT equations of the active set it found, devices at their bounds
+    # and caps held tight
+    x = res.x
+    at_lo, at_hi = x <= lo + 1e-7, x >= hi - 1e-7
+    free = (w > 0) & ~at_lo & ~at_hi
+    x = np.where(at_lo, lo, np.where(at_hi, hi, x))
+    tight = rows[keep] @ x >= row_hi[keep] - 1e-6
+    a = rows[keep][tight]
+    af = a[:, free]
+    mu = np.linalg.lstsq(
+        (af / w[free]) @ af.T,
+        af @ t[free] + a[:, ~free] @ x[~free] - row_hi[keep][tight],
+        rcond=None,
+    )[0]
+    x[free] = t[free] - af.T @ mu / w[free]
+    np.testing.assert_allclose(x, res.x, rtol=0, atol=1e-3)
+    assert (x >= lo).all() and (x <= hi).all()
+    return x
+
+
+def _phase1_reference(ap, pin_free):
+    """Phase I as one level QP per priority level, each solved by an
+    active-set QP whose objective is checked to be no worse than
+    ``refsolve.ref_solve``'s (whose interior point stops short of the
+    active bounds, by up to tens of watts on these problems); a
+    node whose devices' floors exceed its cap holds them at their floors
+    (the cap is then left to the repair).  Returns the Phase I point and
+    the devices so held."""
+    from repro.core.refsolve import ref_solve
+
+    f64 = functools.partial(jnp.asarray, dtype=jnp.float64)
+    start, end = np.asarray(ap.tree.start), np.asarray(ap.tree.end)
+    floors = np.array([math.fsum(np.asarray(ap.l)[a:b]) for a, b in zip(start, end)])
+    over = floors > np.asarray(ap.tree.cap)
+    held = np.zeros(ap.n, bool)
+    for j in np.flatnonzero(over):
+        held[start[j] : end[j]] = True
+    cap = np.where(over, np.inf, np.asarray(ap.tree.cap, np.float64))
+    tree = ap.tree._replace(cap=f64(cap))
+    x = np.asarray(ap.l, np.float64)
+    done = np.zeros(ap.n, bool)
+    for p in ap.priority_levels():
+        mask_a = np.asarray(ap.active & (ap.priority == p))
+        prob = phases.qp_step(
+            ap, jnp.asarray(x, ap.l.dtype), jnp.asarray(mask_a), jnp.asarray(done),
+            1e-5, pin_free=pin_free,
+        )
+        prob = jax.tree_util.tree_map(f64, prob)
+        prob = prob._replace(hi=jnp.where(held, prob.lo, prob.hi))
+        x = _active_set_qp(prob, tree, ap.sla)
+        # at least as good as the interior point's answer
+        w, t = np.asarray(prob.w), np.asarray(prob.target)
+        z = ref_solve(prob, tree, ap.sla)[: ap.n]
+        assert np.sum(w * (x - t) ** 2) <= np.sum(w * (z - t) ** 2) * (1 + 1e-9)
+        done |= mask_a
+    return x, held
+
+
+@pytest.mark.parametrize(
+    "case",
+    [
+        "uniform_w",
+        "inv_u_w",
+        "three_levels",
+        "eps_l",
+        "over_cap_at_entry",
+        "idle",
+        "float32",
+        "vmap_lanes",
+    ],
+)
+def test_phase1_projection_matches_the_reference(case):
+    """On problems with no tenant rows, Phase I through the jitted scan and
+    through the host driver is the exact projection: each equals
+    reference's level QPs to 1e-6 W (1e-4 W in float32), runs no PDHG
+    iteration, and searches exactly where a node binds."""
+    from repro.core.batched import _phase1_scan, batch_meta, stack_problems
+    from repro.core.solver import SolverState
+
+    aps, pin_free = _phase1_lanes(case)
+    stacked = stack_problems(aps)
+    meta = batch_meta(stacked, NvpaxOptions())._replace(pin_free=pin_free)
+    n, m = stacked.n, stacked.tree.m
+    dtype = stacked.l.dtype
+    atol = 1e-4 if dtype == jnp.float32 else 1e-6
+
+    def scan(l, u, r, priority, active, weight_scale):
+        ap = stacked._replace(
+            l=l, u=u, r=r, priority=priority, active=active, weight_scale=weight_scale
+        )
+        warm = SolverState.zeros(n, m, 0, dtype)
+        st = _phase1_scan(ap, meta, pdhg.SolverOptions(), warm)
+        return st.x, st.iterations, st.search_steps, st.search_levels
+
+    lanes = (stacked.l, stacked.u, stacked.r, stacked.priority, stacked.active,
+             stacked.weight_scale)
+    xs, iters, steps, levels = jax.jit(jax.vmap(scan))(*lanes)
+    assert (np.asarray(iters) == 0).all()
+    assert ((np.asarray(steps) > 0) == (np.asarray(levels) > 0)).all()
+    for ap, x, lv in zip(aps, np.asarray(xs), np.asarray(levels)):
+        want, held = _phase1_reference(ap, pin_free)
+        np.testing.assert_allclose(x, want, rtol=0, atol=atol)
+        np.testing.assert_array_equal(x[held], np.asarray(ap.l)[held])
+        if pin_free:
+            x_host, _, st = phases.phase1(ap, pdhg.SolverOptions())
+            np.testing.assert_allclose(np.asarray(x_host), x, rtol=0, atol=1e-9)
+            assert st.iterations == 0 and st.solves == len(ap.priority_levels())
+        # a level is searched exactly where its nodes bind at the requests
+        x0 = np.clip(np.asarray(ap.r), np.asarray(ap.l), np.asarray(ap.u))
+        x0 = np.where(np.asarray(ap.active), x0, np.asarray(ap.l))
+        sums = np.array([math.fsum(x0[a:b]) for a, b in zip(
+            np.asarray(ap.tree.start), np.asarray(ap.tree.end))])
+        if case != "over_cap_at_entry":
+            assert (lv > 0) == bool((sums > np.asarray(ap.tree.cap) + 1e-6).any())
+    if case == "vmap_lanes":
+        assert list(np.asarray(levels)) == [0, 1, 1, 2]
+
+
+@pytest.mark.parametrize("oversubscription, binds", [(0.85, True), (1.0, False)])
+def test_step_reports_the_projection_steps_of_phase_1(oversubscription, binds):
+    """``stats["project_steps_p1"]`` and ``["project_levels_p1"]`` read 0
+    where no node binds (every cap the sum of its children's) and are
+    positive where one does, per lane on the batched path, with no PDHG
+    iteration in Phase I."""
+    from repro.core.batched import optimize_batched
+    from repro.core.engine import AllocEngine
+
+    pdn = build_from_level_sizes(
+        [2, 3, 2], gpus_per_server=4, oversubscription=oversubscription
+    )
+    # busy devices: at oversubscription 0.85 the halls' caps bind (above
+    # 505.75 W a device); then every device idle, with nothing to project
+    tele = np.random.default_rng(5).uniform(500.0, 800.0, (2, pdn.n))
+    tele[1] = 100.0
+    eng = AllocEngine(pdn)
+    res = eng.step(tele[0])
+    steps, levels = res.stats["project_steps_p1"], res.stats["project_levels_p1"]
+    assert (steps > 0, levels > 0) == (binds, binds)
+    assert res.stats["phase_iterations"][0] == 0
+    assert eng.history[-1]["project_steps_p1"] == steps
+    assert eng.history[-1]["project_levels_p1"] == levels
+    bres = optimize_batched([AllocProblem.build(pdn, t) for t in tele])
+    np.testing.assert_array_equal(bres.stats["project_steps_p1"], [steps, 0])
+    np.testing.assert_array_equal(bres.stats["project_levels_p1"], [levels, 0])
+
+
+def test_phase1_keeps_pdhg_on_problems_with_tenant_rows():
+    """With tenant rows Phase I runs the PDHG program it ran before the
+    projection existed: the same iterations and the same caps as the
+    parent commit's (200 iterations a lane; the Phase I sums below), and
+    no projection step."""
+    from repro.core.batched import optimize_batched
+    from repro.pdn.tenants import assign_tenants
+
+    pdn = build_from_level_sizes([2, 3, 2], gpus_per_server=4)
+    layout = assign_tenants(pdn, n_tenants=4, devices_per_tenant=8, seed=1)
+    reqs = np.random.default_rng(1).uniform(100, 650, (3, pdn.n))
+    aps = [
+        AllocProblem.build(pdn, r, sla=layout.sla_topo(), priority=layout.priority)
+        for r in reqs
+    ]
+    res = optimize_batched(aps)
+    np.testing.assert_array_equal(res.stats["phase_iterations"][:, 0], [200] * 3)
+    np.testing.assert_allclose(
+        res.phase1.sum(axis=1),
+        [18883.667708152192, 18691.41086058721, 18091.801983496116],
+        rtol=0,
+        atol=1e-6,
+    )
+    assert (res.stats["project_steps_p1"] == 0).all()
+    x1, _, st = phases.phase1(aps[0], pdhg.SolverOptions())
+    assert st.iterations == 200
+    np.testing.assert_allclose(np.asarray(x1), res.phase1[0], rtol=0, atol=1e-9)
