@@ -155,7 +155,8 @@ def run_degenerate(n_seeds: int = 2) -> dict:
                 ap = AllocProblem.build(
                     pdn, tele, sla=lay.sla_topo(), priority=lay.priority
                 )
-                x1, state, _ = phases.phase1(ap, solver.SolverOptions())
+                p1 = optimize(ap, NvpaxOptions(run_phase2=False, run_phase3=False))
+                x1, state = jnp.asarray(p1.phase1), p1.warm_state.p1
                 mask_a = ap.active & ~phases.saturated_mask(x1, ap, ap.active)
                 prob = phases.lp_step(
                     ap, x1, mask_a, ~(mask_a | ap.idle), ap.idle, 1e-5

@@ -43,8 +43,8 @@ def main():
     ):
         print(f"{name:8s}: total {base.sum() / 1e3:.1f} kW  "
               f"satisfaction {100 * satisfaction_ratio(r, base):.2f}%")
-    print(f"\nsolver: {result.stats['total_solves']} convex solves, "
-          f"{result.stats['total_iterations']} PDHG iterations, "
+    print(f"\nsolver: {result.stats['solves']} convex solves, "
+          f"{result.stats['iterations']} PDHG iterations, "
           f"{1000 * result.wall_time_s:.0f} ms wall")
 
 

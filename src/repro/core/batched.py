@@ -1,41 +1,30 @@
-"""Fully-jitted batched three-phase allocation engine (Algorithm 3 under
-``jax.vmap``).
+"""The three-phase allocation driver (Algorithm 3), fully jitted and
+batched under ``jax.vmap``.
 
-The host drivers in :mod:`repro.core.phases` orchestrate the three nvPAX
-phases with Python control flow — a priority sweep with host-side level
-enumeration, saturation rounds with ``np.asarray(...).any()`` early exits,
-and a host water-filling fast path.  That is the right shape for the
-closed-loop controller (one problem per 30 s interval, per-phase wall-clock
-stats, deadline truncation), but it serializes MPC what-if sweeps,
-per-tenant scenario evaluation, and robustness studies, which need *many*
-solves per control step.
-
-This module re-expresses the same algorithm as a fixed-shape jax program:
+:func:`solve_three_phase` is the one three-phase driver.  It runs one
+scenario as a fixed-shape jax program:
 
 * the Phase I priority sweep is a ``lax.scan`` over the problem's
   precomputed priority-level metadata (``AllocProblem.priority_levels``),
-  with per-scenario empty levels skipped by ``lax.cond`` so sweep semantics
-  match the host driver exactly;
+  with per-scenario empty levels skipped by ``lax.cond``;
 * the Phase II/III saturation rounds are a ``lax.while_loop`` over a
-  :class:`BatchedStepState`, with the host driver's two exit tests (empty
-  optimized set; no measurable head-room and nothing newly saturated)
-  evaluated as traced predicates;
-* the exact feasibility repair is the shared fixed-trip
-  ``phases.repair(..., n_depths)`` fori-loop;
+  :class:`BatchedStepState`, with the two exit tests (empty optimized set;
+  no measurable head-room and nothing newly saturated) evaluated as traced
+  predicates;
+* the step problems (``qp_step``, ``lp_step``), saturation detection and
+  the exact feasibility repair (a fixed-trip ``fori_loop``) are the shared
+  builders of :mod:`repro.core.phases`;
 * on SLA-free problems each Phase I level QP is the exact weighted tree
   projection :func:`repro.core.waterfill.tree_project_jax`, and the
   max-min fast path is the level-wise tree projection
   :func:`repro.core.waterfill.waterfill_project_jax`.
 
-Because every step-problem builder (``qp_step``, ``lp_step``,
-``saturated_mask``, ``repair``) is imported from :mod:`repro.core.phases`,
-the host and jitted paths cannot drift: they build bit-identical convex
-programs and differ only in orchestration.
-
-The whole three-phase policy therefore compiles once per
+The whole three-phase policy compiles once per
 ``(n, m, k, n_priority_levels)`` shape and is ``vmap``-ed over K request
-scenarios into one accelerator program — :func:`optimize_batched` is the
-public entry point.
+scenarios into one accelerator program.  :func:`optimize_batched` is the
+K-lane entry point, :func:`repro.core.nvpax.optimize` the one-lane case of
+the same program, and :class:`repro.core.engine.AllocEngine` and the
+sharded fleet trace :func:`solve_three_phase` into their own step programs.
 """
 
 from __future__ import annotations
@@ -68,7 +57,6 @@ __all__ = [
     "optimize_batched",
     "PhaseCostModel",
     "calibrate_phase_cost",
-    "calibrate_iter_cost",
 ]
 
 
@@ -276,8 +264,8 @@ def _phase1_scan(
                 search_levels=st.search_levels,
             )
 
-        # the host driver only sweeps levels present among this scenario's
-        # active devices; skip empty levels to match it exactly
+        # sweep only the levels present among this scenario's active
+        # devices: an empty level has nothing to solve
         pred = jnp.any(mask_a)
         if skip is not None:
             pred = pred & ~skip
@@ -307,8 +295,8 @@ def _maxmin_loop(
     by earlier phases) is the anytime/deadline mode: the saturation loop
     stops as soon as the cumulative iteration count crosses the budget.
     Every round ends with the exact feasibility repair, so the truncated
-    allocation is feasible — the same phase/round-boundary-anytime property
-    the host driver gets from its wall-clock deadline.
+    allocation is feasible: the driver is anytime at phase and round
+    boundaries.
 
     ``skip`` (incremental mode) enters the loop condition, so a certified
     step exits before the first round — and under ``vmap`` a skipped lane
@@ -337,7 +325,8 @@ def _maxmin_loop(
             search_levels=levels,
         )
 
-    # freeze devices with no slack at entry (see phases.run_maxmin_phase)
+    # freeze devices with no slack at entry: otherwise they force t* = 0 and
+    # the eps-term would spread the surplus instead of raising it max-min
     mask0 = opt_set & ~phases.saturated_mask(x, ap, opt_set)
     init = BatchedStepState(
         x=x,
@@ -376,13 +365,13 @@ def _maxmin_loop(
         sol, stats = solver.solve(prob, ap.tree, ap.sla, sol, opts)
         # monotone non-decrease on non-free devices: the dualized
         # improvement rows guarantee it only at convergence, so enforce it
-        # against truncated solves (mirrors phases.run_maxmin_phase; keeps
-        # Phase I's tenant minimums intact through stalled LP rounds)
+        # against truncated solves (keeps Phase I's tenant minimums intact
+        # through stalled LP rounds)
         x_cand = jnp.where(free_set, sol.x, jnp.maximum(sol.x, st.x))
         x_new = phases.repair(x_cand, ap, meta.n_depths)
         sat = phases.saturated_mask(x_new, ap, st.mask)
-        # host driver: stop when no measurable head-room is left AND nothing
-        # newly saturated needs freezing
+        # stop when no measurable head-room is left AND nothing newly
+        # saturated needs freezing
         done = (sol.t <= phases.SAT_TOL) & ~jnp.any(sat)
         res = jnp.maximum(
             jnp.maximum(stats.primal_res, stats.dual_res), stats.comp_res
@@ -419,13 +408,13 @@ def solve_three_phase(
     ``warm`` is the per-phase carry from the previous control step (see
     :class:`repro.core.phases.WarmCarry`): each phase warm-starts its duals
     from the same phase's previous end state, with the primal chained
-    through the current step — identical semantics to the host driver.
+    through the current step.
 
-    ``iter_budget`` is the deadline/anytime mode, mirroring the host
-    driver's ``NvpaxOptions.deadline_s`` semantics in iteration space
-    (callers derive the budget from a wall-clock deadline and a calibrated
-    per-iteration cost, see :func:`calibrate_iter_cost`): Phase I always
-    runs — it carries feasibility and request satisfaction — and each
+    ``iter_budget`` is the deadline/anytime mode, ``NvpaxOptions.deadline_s``
+    in iteration space (callers derive the budget from a wall-clock
+    deadline and a calibrated per-iteration cost, see
+    :func:`calibrate_phase_cost`): Phase I always runs — it carries
+    feasibility and request satisfaction — and each
     refinement phase (II: active surplus, III: idle surplus) starts only if
     the cumulative PDHG iteration count is still under budget, then stops at
     the first saturation round that crosses it.  Passing a traced/concrete
@@ -803,7 +792,12 @@ class PhaseCostModel(NamedTuple):
             c23 = max(max(wall_full - c1 * phases_full[0], 0.0) / it23, 0.5 * c1)
         else:
             c23 = c1
-        tot = max(sum(phases_full), 1)
+        tot = sum(phases_full)
+        if tot == 0:
+            # no PDHG iteration at all (tenant-free problems, where Phase I
+            # projects and the max-min phases fill): price each budgeted
+            # iteration at the whole Phase-I-only probe
+            return cls(p1_s=c1, p23_s=c23, mix=(1.0, 0.0))
         return cls(p1_s=c1, p23_s=c23, mix=(phases_full[0] / tot, it23 / tot))
 
 
@@ -859,16 +853,6 @@ def calibrate_phase_cost(
     return _ITER_COST_CACHE[key]
 
 
-def calibrate_iter_cost(
-    stacked: AllocProblem,
-    meta: BatchMeta,
-    opts: solver.SolverOptions,
-) -> float:
-    """Mix-weighted scalar seconds-per-iteration (compat wrapper around
-    :func:`calibrate_phase_cost`)."""
-    return calibrate_phase_cost(stacked, meta, opts).cost_per_iter()
-
-
 # ---------------------------------------------------------------------------
 # public entry point
 # ---------------------------------------------------------------------------
@@ -901,11 +885,11 @@ def optimize_batched(
 
     Deadline mode: ``options.deadline_s`` is honored by translating the
     wall-clock deadline into a per-scenario PDHG iteration budget via
-    :func:`calibrate_iter_cost` (one-time per shape) — Phase I always runs,
+    :func:`calibrate_phase_cost` (one-time per shape) — Phase I always runs,
     refinement phases are skipped or cut at saturation-round granularity,
-    and ``stats["truncated"]`` reports per-scenario truncation, matching the
-    host path's phase-boundary anytime semantics.  ``iter_budget`` passes an
-    explicit budget instead (overrides ``deadline_s``).
+    and ``stats["truncated"]`` reports per-scenario truncation.
+    ``iter_budget`` passes an explicit budget instead (overrides
+    ``deadline_s``).
 
     Incremental mode: ``carry`` threads the previous step's
     ``BatchedAllocResult.carry`` back in; per-scenario certify flags land in
@@ -917,8 +901,50 @@ def optimize_batched(
     see :func:`repro.obs.recorder.init_batch`); the updated state comes back
     as ``BatchedAllocResult.recorder``.
 
-    Output matches per-scenario :func:`repro.core.nvpax.optimize` to solver
-    tolerance (asserted in ``tests/test_batched.py``).
+    Each lane matches the one-lane :func:`repro.core.nvpax.optimize` to
+    solver tolerance (asserted in ``tests/test_batched.py``).
+    """
+    out, iter_budget, wall = _run_batched(
+        aps, options, warm, meta=meta, iter_budget=iter_budget, carry=carry,
+        rec=rec, rec_cfg=rec_cfg,
+    )
+    x1, x2, x3, sol_state, stats, new_carry, new_rec = out
+    return BatchedAllocResult(
+        allocation=np.asarray(x3),
+        phase1=np.asarray(x1),
+        phase2=np.asarray(x2),
+        warm_state=sol_state,
+        wall_time_s=wall,
+        carry=new_carry if carry is not None or options.incremental else None,
+        recorder=new_rec if rec is not None else None,
+        stats=StepStats.from_jit(
+            stats,
+            iter_budget=iter_budget,
+            n_scenarios=int(x3.shape[0]),
+        ),
+    )
+
+
+def _run_batched(
+    aps: Sequence[AllocProblem] | AllocProblem,
+    options: NvpaxOptions,
+    warm: phases.WarmCarry | None = None,
+    *,
+    meta: BatchMeta | None = None,
+    iter_budget: int | None = None,
+    carry: Any = None,
+    rec: Any = None,
+    rec_cfg: Any = None,
+):
+    """Stack ``aps``, price ``options.deadline_s`` and run
+    :func:`_solve_batched` under the options' precision: the shared body of
+    :func:`optimize_batched` and the one-lane
+    :func:`repro.core.nvpax.optimize`.
+
+    Returns ``(outputs, iter_budget, wall_s)``: the program's seven outputs
+    with ``[K, ...]`` leaves, the iteration budget served (``None``
+    without a deadline) and the wall time through the allocation's
+    readiness.
     """
     ctx = jax.enable_x64(True) if options.x64 else contextlib.nullcontext()
     t0 = time.perf_counter()
@@ -936,22 +962,8 @@ def optimize_batched(
         budget = (
             None if iter_budget is None else jnp.asarray(iter_budget, jnp.int32)
         )
-        x1, x2, x3, sol_state, stats, new_carry, new_rec = _solve_batched(
+        out = _solve_batched(
             stacked, meta, options.solver, warm, budget, carry, rec, rec_cfg
         )
-        x3 = x3.block_until_ready()
-    wall = time.perf_counter() - t0
-    return BatchedAllocResult(
-        allocation=np.asarray(x3),
-        phase1=np.asarray(x1),
-        phase2=np.asarray(x2),
-        warm_state=sol_state,
-        wall_time_s=wall,
-        carry=new_carry if carry is not None or options.incremental else None,
-        recorder=new_rec if rec is not None else None,
-        stats=StepStats.from_jit(
-            stats,
-            iter_budget=iter_budget,
-            n_scenarios=int(stacked.l.shape[0]),
-        ),
-    )
+        out[2].block_until_ready()
+    return out, iter_budget, time.perf_counter() - t0

@@ -4,8 +4,7 @@ steps.
 :class:`AllocEngine` is the production serving shape of the allocator.  The
 per-step cost of the rebuild-every-step path (``AllocProblem.build`` +
 ``nvpax.optimize``) is dominated by host-side work we re-pay every control
-interval: topology re-derivation and device upload, Python phase
-orchestration with per-solve device syncs, and host water-filling.  The
+interval: topology re-derivation, device upload and request shaping.  The
 engine is constructed **once per fleet** — PDN tree + SLA topology +
 priority layout — and then serves every control step with zero host-side
 rebuild work:
@@ -20,14 +19,13 @@ rebuild work:
   telemetry pre-processing (clip to box, idle -> l), all three phases,
   feasibility repair — a single dispatch with no phase-boundary host hops;
 * warm starts are carried across control steps automatically, in both the
-  host (:meth:`step`) and batched (:meth:`step_batched`) paths — an
+  one-scenario (:meth:`step`) and batched (:meth:`step_batched`) paths — an
   optimization, not a correctness dependency (:meth:`reset_warm` restores
   cold start, e.g. after fleet geometry changes);
 * deadlines run in iteration space: ``options.deadline_s`` (or a per-call
   override) is translated into a PDHG iteration budget via a one-time
-  calibrated per-iteration cost, giving the fully-jitted step the same
-  phase-boundary anytime semantics (``stats["truncated"]``) as the
-  wall-clock host path.
+  calibrated per-iteration cost, with phase-boundary anytime semantics
+  (``stats["truncated"]``) as in :func:`repro.core.nvpax.optimize`.
 
 The engine is *shape*-pinned, not *value*-pinned: the fleet topology enters
 the compiled program as traced arrays, so any same-shape change — a supply
@@ -164,8 +162,8 @@ class AllocEngine:
     Parameters mirror ``AllocProblem.build``: the PDN, optional tenant SLA
     topology, a fixed priority layout, and ``NvpaxOptions``.  ``step`` then
     takes only telemetry (+ optional scheduler active mask) and returns the
-    same :class:`~repro.core.nvpax.AllocResult` as the host path — matching
-    it to solver tolerance (see ``tests/test_engine.py``).
+    same :class:`~repro.core.nvpax.AllocResult` as ``nvpax.optimize`` —
+    matching it to solver tolerance (see ``tests/test_engine.py``).
     """
 
     def __init__(
@@ -214,8 +212,8 @@ class AllocEngine:
             pin_free = sla_t.k == 0 or not bool((np.asarray(sla_t.lo) > 0).any())
         # levels from the full priority layout (not the per-step active set):
         # the Phase I scan skips empty levels with a traced cond, so the
-        # compiled program is pinned while per-step semantics match the host
-        # driver's active-only sweep exactly.
+        # compiled program is pinned while per-step semantics match
+        # nvpax.optimize's active-only sweep exactly.
         self.meta = BatchMeta(
             levels=tuple(sorted({int(p) for p in self.priority_np}, reverse=True)),
             n_depths=int(pdn.node_depth.max()) + 1 if pdn.m else 0,
@@ -523,7 +521,7 @@ class AllocEngine:
                     )
                 # None (cold) and carry (steady) are two jit variants; the
                 # cold one must stay warm=None so its phase chaining is
-                # bit-identical to the host driver's cold path.
+                # that of a cold nvpax.optimize call.
                 with spans.span("engine.dispatch"):
                     x1, x2, x3, solver, stats, new_carry, new_rec = _engine_step_jit(
                         self.fleet,

@@ -1,22 +1,21 @@
 """nvPAX — Algorithm 3: the full three-phase power allocation policy.
 
-``optimize()`` is the public entry point invoked by the closed-loop power
-controller every control step.  It is deterministic, always returns a
-feasible allocation (exact repair, section "repair" of phases.py), and
-supports warm starting across control steps (paper section 5.6 "additional
-speedups are possible via ... warm-starting across control steps" — we
-implement it, see EXPERIMENTS.md §Perf).
+``optimize()`` is the public entry point for one control step's problem.  It
+is deterministic, always returns a feasible allocation (exact repair, section
+"repair" of phases.py), and supports warm starting across control steps
+(paper section 5.6 "additional speedups are possible via ... warm-starting
+across control steps").  It runs the jitted three-phase driver
+:func:`repro.core.batched.solve_three_phase` as a one-lane batch: the same
+compiled program :func:`repro.core.batched.optimize_batched` serves K lanes
+with, and the one :class:`repro.core.engine.AllocEngine` steps.
 """
 
 from __future__ import annotations
 
-import contextlib
-import time
 from dataclasses import dataclass, field
 from typing import Any
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.core import phases
@@ -41,16 +40,12 @@ class NvpaxOptions:
     use_waterfill: bool = True
     # Anytime / deadline-aware mode (the paper's stated future work,
     # section 6): every phase boundary is a valid, feasible allocation, so
-    # when the elapsed wall time exceeds the deadline the remaining
-    # refinement phases (II: active surplus, III: idle surplus) are
-    # truncated and the best-so-far allocation is returned with
+    # the deadline is translated into a PDHG iteration budget via a
+    # calibrated per-iteration cost; the refinement phases (II: active
+    # surplus, III: idle surplus) are skipped or cut at saturation-round
+    # granularity and the best-so-far allocation is returned with
     # stats["truncated"]=True.  Phase I always runs: it carries feasibility
-    # and request satisfaction.  The host path (this module) checks wall
-    # clock at phase boundaries; the fully-jitted paths
-    # (repro.core.batched.optimize_batched, repro.core.engine.AllocEngine)
-    # translate the deadline into a PDHG iteration budget via a calibrated
-    # per-iteration cost and truncate at saturation-round granularity with
-    # the same stats["truncated"] reporting.
+    # and request satisfaction.
     deadline_s: float | None = None
     # Incremental re-solve (PR 7): certify the carried solution against the
     # new step before solving (see repro.core.solver.certify).  When enabled,
@@ -95,129 +90,29 @@ def optimize(
     solve is skipped entirely (``stats["skipped"]``) or restarted after
     Phase I (``stats["certify_pass"]``) — see ``repro.core.solver.certify``.
     """
-    ctx = jax.enable_x64(True) if options.x64 else contextlib.nullcontext()
-    t0 = time.perf_counter()
+    # batched imports NvpaxOptions from this module
+    from repro.core import batched
 
-    def in_budget() -> bool:
-        return (
-            options.deadline_s is None
-            or time.perf_counter() - t0 < options.deadline_s
-        )
+    def lane(tree):
+        return jax.tree_util.tree_map(lambda a: a[None], tree)
 
-    truncated = False
-    with ctx:
-        skipped = p1_reused = False
-        if options.incremental and carry is not None:
-            dec = solver_mod.certify_step(
-                ap,
-                carry,
-                ap.n_tree_depths(),
-                tol=options.certify_tol,
-                margin=options.certify_margin,
-                opts=options.solver,
-            )
-            skipped = bool(dec.skip)
-            p1_reused = bool(dec.skip_p1)
-        if skipped:
-            x3 = dec.x_snap.block_until_ready()
-            zero = phases.PhaseStats(0, 0, True, 0.0)
-            return AllocResult(
-                allocation=np.asarray(x3),
-                phase1=np.asarray(carry.x1),
-                phase2=np.asarray(carry.x2),
-                warm_state=warm,
-                wall_time_s=time.perf_counter() - t0,
-                stats=StepStats.build(
-                    solves=0,
-                    iterations=0,
-                    phase_iterations=[0, 0, 0],
-                    converged=True,
-                    skipped=True,
-                    certify_pass=True,
-                    kkt_certified=True,
-                    truncated=False,
-                    phase1=zero._asdict(),
-                    phase2=zero._asdict(),
-                    phase3=zero._asdict(),
-                ),
-                carry=carry,
-            )
-        if p1_reused:
-            x1 = jnp.asarray(carry.x1)
-            s1 = phases.PhaseStats(0, 0, True, 0.0)
-            w1 = (
-                warm.p1
-                if warm
-                else solver_mod.SolverState.zeros(
-                    ap.n, ap.tree.m, ap.sla.k, ap.l.dtype
-                )
-            )
-            state = w1._replace(x=x1)
-        else:
-            x1, state, s1 = phases.phase1(
-                ap, options.solver, options.eps, warm.p1 if warm else None,
-                use_waterfill=options.use_waterfill,
-            )
-        carry1 = state
-        x2 = x1
-        s2 = phases.PhaseStats(0, 0, True, 0.0)
-        state = phases.merge_warm(state, warm.p2 if warm else None)
-        if options.run_phase2 and in_budget():
-            x2, state, s2 = phases.run_maxmin_phase(
-                ap, x1, ap.active, ap.idle, options.solver, options.eps, state,
-                options.max_rounds, use_waterfill=options.use_waterfill,
-            )
-        elif options.run_phase2:
-            truncated = True
-        carry2 = state
-        x3 = x2
-        s3 = phases.PhaseStats(0, 0, True, 0.0)
-        state = phases.merge_warm(state, warm.p3 if warm else None)
-        if options.run_phase3 and in_budget():
-            empty = jnp.zeros_like(ap.active)
-            x3, state, s3 = phases.run_maxmin_phase(
-                ap, x2, ap.idle, empty, options.solver, options.eps, state,
-                options.max_rounds, use_waterfill=options.use_waterfill,
-            )
-        elif options.run_phase3:
-            truncated = True
-        carry3 = state
-        x3 = x3.block_until_ready()
-        new_carry = None
-        if options.incremental:
-            if p1_reused:
-                new_carry = carry._replace(
-                    x2=jnp.asarray(x2),
-                    x=jnp.asarray(x3),
-                    cap=ap.tree.cap,
-                    sla_lo=ap.sla.lo,
-                    sla_hi=ap.sla.hi,
-                )
-            else:
-                new_carry = solver_mod.make_carry(
-                    ap, jnp.asarray(x1), jnp.asarray(x2), jnp.asarray(x3)
-                )
-    wall = time.perf_counter() - t0
+    def unlane(tree):
+        return jax.tree_util.tree_map(lambda a: a[0], tree)
+
+    out, budget, wall = batched._run_batched(
+        [ap],
+        options,
+        lane(warm),
+        carry=lane(carry) if options.incremental else None,
+    )
+    x1, x2, x3, warm_state, stats, new_carry, _ = out
+    stats = jax.tree_util.tree_map(lambda a: np.asarray(a)[0], jax.device_get(stats))
     return AllocResult(
-        allocation=np.asarray(x3),
-        phase1=np.asarray(x1),
-        phase2=np.asarray(x2),
-        warm_state=phases.WarmCarry(carry1, carry2, carry3),
+        allocation=np.asarray(x3[0]),
+        phase1=np.asarray(x1[0]),
+        phase2=np.asarray(x2[0]),
+        warm_state=unlane(warm_state),
         wall_time_s=wall,
-        stats=StepStats.build(
-            solves=s1.solves + s2.solves + s3.solves,
-            iterations=s1.iterations + s2.iterations + s3.iterations,
-            phase_iterations=[s1.iterations, s2.iterations, s3.iterations],
-            converged=s1.converged and s2.converged and s3.converged,
-            skipped=False,
-            certify_pass=p1_reused,
-            kkt_certified=s1.kkt_certified
-            and s2.kkt_certified
-            and s3.kkt_certified,
-            truncated=truncated,
-            phase1=s1._asdict(),
-            phase2=s2._asdict(),
-            phase3=s3._asdict(),
-        ),
-        carry=new_carry,
+        stats=StepStats.from_jit(stats, scalar=True, iter_budget=budget),
+        carry=unlane(new_carry) if options.incremental else None,
     )

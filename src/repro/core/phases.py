@@ -1,10 +1,11 @@
-"""The three nvPAX phases (paper section 4.3) + feasibility repair and
-saturation detection.
+"""The building blocks of the three nvPAX phases (paper section 4.3): the
+per-phase warm carry, exact feasibility repair, saturation detection and the
+Phase I / Phase II-III step-problem builders.
 
-Orchestration is host-level Python (priority sweep, saturation rounds); the
-inner convex solves are the single jitted program of :mod:`repro.core.solver`,
-warm-started across rounds.  A fully-jitted variant for batched/vmapped
-evaluation lives in :mod:`repro.core.batched`.
+All of them are trace-safe.  The one three-phase driver that orchestrates
+them (priority sweep, saturation rounds) is
+:func:`repro.core.batched.solve_three_phase`; the incremental certify pass
+(:mod:`repro.core.solver.certify`) and the fleet orchestrator use them too.
 """
 
 from __future__ import annotations
@@ -13,22 +14,19 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax import lax
 
 from repro.core import solver
 from repro.core.problem import INF, AllocProblem, StepProblem
 from repro.core.treeops import sla_matvec, sla_rmatvec, tree_matvec, tree_rmatvec
-from repro.core.waterfill import tree_project_jax
 
 __all__ = [
-    "PhaseStats",
     "WarmCarry",
     "merge_warm",
     "repair",
     "saturated_mask",
-    "phase1",
-    "run_maxmin_phase",
+    "qp_step",
+    "lp_step",
 ]
 
 # Tolerance (watts) for saturation detection, matching the paper's "no
@@ -37,16 +35,6 @@ SAT_TOL = 1e-3
 # Max saturation rounds; each round freezes >= 1 device or the loop exits on
 # no-progress, so this is a safety net, not a truncation (asserted in tests).
 MAX_ROUNDS = 40
-
-
-class PhaseStats(NamedTuple):
-    solves: int
-    iterations: int
-    converged: bool
-    max_primal_res: float
-    # every inner solve exited KKT-certified (False when any solve exited on
-    # the no-progress/optimal-vertex certificate — see solver.termination)
-    kkt_certified: bool = True
 
 
 class WarmCarry(NamedTuple):
@@ -64,8 +52,9 @@ class WarmCarry(NamedTuple):
     ``tests/test_engine.py``).
 
     A pytree of :class:`repro.core.solver.SolverState` leaves, so the same
-    carry works for the host driver (:func:`repro.core.nvpax.optimize`), the
-    fully-jitted engine, and the vmapped batched path (``[K, ...]`` leaves).
+    carry works for one problem (:func:`repro.core.nvpax.optimize`, the
+    engine's :meth:`~repro.core.engine.AllocEngine.step`) and for the
+    vmapped batched path (``[K, ...]`` leaves).
     """
 
     p1: solver.SolverState
@@ -109,10 +98,9 @@ def repair(
     *minimums* can in principle lose up to the solver tolerance; tests bound
     this below 1e-6 W.
 
-    Trace-safe: the per-depth sweep is a fixed-trip ``lax.fori_loop``, so
-    the same code serves the host drivers and the fully-jitted batched
-    engine.  ``n_depths`` (static) must be supplied when ``ap`` holds
-    tracers; it defaults to ``ap.n_tree_depths()`` on concrete problems.
+    Trace-safe: the per-depth sweep is a fixed-trip ``lax.fori_loop``.
+    ``n_depths`` (static) must be supplied when ``ap`` holds tracers; it
+    defaults to ``ap.n_tree_depths()`` on concrete problems.
     """
     if n_depths is None:
         n_depths = ap.n_tree_depths()
@@ -259,144 +247,3 @@ def lp_step(
         sla_hi=ap.sla.hi,
         imp_lo=jnp.where(mask_a, base, -INF).astype(dtype),
     )
-
-
-# ---------------------------------------------------------------------------
-# phase drivers
-# ---------------------------------------------------------------------------
-
-
-_tree_project = jax.jit(tree_project_jax, static_argnames="n_depths")
-
-
-def phase1(
-    ap: AllocProblem,
-    opts: solver.SolverOptions,
-    eps: float = 1e-5,
-    warm: solver.SolverState | None = None,
-    use_waterfill: bool = True,
-) -> tuple[jnp.ndarray, solver.SolverState, PhaseStats]:
-    """Algorithm 1: priority-ordered request satisfaction.
-
-    When no tenant SLAs are present (``use_waterfill=True``) each level QP
-    is a weighted projection onto the box and the tree's caps, solved
-    exactly by :func:`repro.core.waterfill.tree_project_jax`; the solver
-    state passes through untouched.  With SLAs the PDHG solve is required.
-    """
-    n, m, k = ap.n, ap.tree.m, ap.sla.k
-    dtype = ap.l.dtype
-    state = warm if warm is not None else solver.SolverState.zeros(n, m, k, dtype)
-    x = ap.l
-    finalized = jnp.zeros((n,), bool)
-    # Sweep order and the pin-free simplification (paper 4.3.1) come from the
-    # problem's precomputed level metadata — the same metadata that
-    # parameterizes the fully-jitted engine in repro.core.batched, so the
-    # host and jitted paths cannot drift.
-    levels = ap.priority_levels(active_only=True)
-    pin_free = ap.pin_free_ok()
-    n_depths = ap.n_tree_depths()
-    solves = iters = 0
-    conv = cert = True
-    maxres = 0.0
-    project = use_waterfill and k == 0
-    for p in levels:
-        mask_a = ap.active & (ap.priority == p)
-        prob = qp_step(ap, x, mask_a, finalized, eps, pin_free=pin_free)
-        if project:
-            x, _, _ = _tree_project(
-                prob.target, prob.w, prob.lo, prob.hi, ap.tree, n_depths
-            )
-            x = repair(x, ap, n_depths)
-            finalized = finalized | mask_a
-            solves += 1
-            continue
-        state = solver.SolverState(x, state.t, state.y_tree, state.y_sla, state.y_imp)
-        state, stats = solver.solve(prob, ap.tree, ap.sla, state, opts)
-        x = repair(state.x, ap, n_depths)
-        finalized = finalized | mask_a
-        solves += 1
-        iters += int(stats.iterations)
-        conv &= bool(stats.converged)
-        cert &= bool(stats.certified)
-        maxres = max(maxres, float(stats.primal_res))
-    return x, state, PhaseStats(solves, iters, conv, maxres, cert)
-
-
-def run_maxmin_phase(
-    ap: AllocProblem,
-    x: jnp.ndarray,
-    opt_set: jnp.ndarray,
-    free_set: jnp.ndarray,
-    opts: solver.SolverOptions,
-    eps: float = 1e-5,
-    warm: solver.SolverState | None = None,
-    max_rounds: int = MAX_ROUNDS,
-    use_waterfill: bool = True,
-) -> tuple[jnp.ndarray, solver.SolverState, PhaseStats]:
-    """Algorithm 2: iterated max-min LP with saturation detection.
-
-    Phase II: ``opt_set`` = active, ``free_set`` = idle.
-    Phase III: ``opt_set`` = idle, ``free_set`` = empty (active pinned).
-
-    When no tenant SLAs are present the feasible set is box + tree only and
-    the iterated-LP limit is the lexicographic max-min allocation, which the
-    exact water-filling sweep computes directly (``use_waterfill=True``,
-    cross-validated against the LP path in tests).  With SLAs the LP path is
-    required — tenant rows couple devices across subtrees.
-    """
-    n, m, k = ap.n, ap.tree.m, ap.sla.k
-    if use_waterfill and k == 0:
-        from repro.core.waterfill import waterfill_arrays
-
-        x_wf = waterfill_arrays(
-            np.asarray(ap.tree.start),
-            np.asarray(ap.tree.end),
-            np.asarray(ap.tree.cap),
-            np.asarray(ap.u),
-            np.asarray(x),
-            np.asarray(opt_set),
-        )
-        state = warm if warm is not None else solver.SolverState.zeros(
-            n, m, k, ap.l.dtype
-        )
-        return jnp.asarray(x_wf), state, PhaseStats(0, 0, True, 0.0)
-    dtype = ap.l.dtype
-    state = warm if warm is not None else solver.SolverState.zeros(n, m, k, dtype)
-    # Devices with no slack at entry (e.g. already at u after Phase I, or under
-    # a cap Phase I left tight) must be frozen before the first round —
-    # otherwise they force t* = 0 and the eps-term would distribute surplus
-    # arbitrarily instead of max-min fairly.
-    mask_a = opt_set & ~saturated_mask(x, ap, opt_set)
-    n_depths = ap.n_tree_depths()
-    solves = iters = 0
-    conv = cert = True
-    maxres = 0.0
-    for _ in range(max_rounds):
-        if not bool(np.asarray(mask_a).any()):
-            break
-        mask_f = ~(mask_a | free_set)
-        prob = lp_step(ap, x, mask_a, mask_f, free_set, eps)
-        state = solver.SolverState(
-            x, jnp.zeros((), dtype), state.y_tree, state.y_sla, state.y_imp
-        )
-        state, stats = solver.solve(prob, ap.tree, ap.sla, state, opts)
-        # The exact max-min iteration never moves a non-free device below
-        # its round-entry value (improvement rows force x >= base + t,
-        # t >= 0), but those rows are dualized: a truncated solve can leave
-        # the primal below base, silently destroying tenant minimums that
-        # Phase I enforced.  Clamp to the invariant before the repair.
-        x_cand = jnp.where(free_set, state.x, jnp.maximum(state.x, x))
-        x_new = repair(x_cand, ap, n_depths)
-        solves += 1
-        iters += int(stats.iterations)
-        conv &= bool(stats.converged)
-        cert &= bool(stats.certified)
-        maxres = max(maxres, float(stats.primal_res))
-        sat = saturated_mask(x_new, ap, mask_a)
-        t_star = float(state.t)
-        no_new_sat = not bool(np.asarray(sat).any())
-        x = x_new
-        if t_star <= SAT_TOL and no_new_sat:
-            break  # no measurable head-room left and nothing to freeze
-        mask_a = mask_a & ~sat
-    return x, state, PhaseStats(solves, iters, conv, maxres, cert)

@@ -139,9 +139,8 @@ class AllocProblem(NamedTuple):
 
     # -- precomputed level metadata (host-side; requires concrete arrays) --
     #
-    # These drive the fixed-trip jax control flow shared by the host drivers
-    # in :mod:`repro.core.phases` and the fully-jitted engine in
-    # :mod:`repro.core.batched`: the priority sweep scans over
+    # These drive the fixed-trip jax control flow of the three-phase driver
+    # in :mod:`repro.core.batched`: the priority sweep scans over
     # ``priority_levels()`` and the feasibility repair runs
     # ``n_tree_depths()`` fori-loop trips.
 
@@ -149,7 +148,7 @@ class AllocProblem(NamedTuple):
         """Distinct priority values, descending (Algorithm 1 sweep order).
 
         ``active_only`` restricts to levels present among active devices —
-        the host driver's behavior.  Must be called on concrete (untraced)
+        what :func:`repro.core.batched.batch_meta` compiles.  Must be called on concrete (untraced)
         arrays; the result is static metadata for jitted programs.
         """
         pri = np.asarray(self.priority)

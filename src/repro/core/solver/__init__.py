@@ -6,13 +6,11 @@ factorizations.  This package is the TPU-native replacement (DESIGN.md
 section 2): a Chambolle-Pock primal-dual iteration whose only
 non-elementwise work is the structured constraint matvec of
 :mod:`repro.core.treeops` (prefix sums + gathers + segment sums), shared by every
-consumer — the host phase drivers (:mod:`repro.core.phases`), the
-vmapped batched engine (:mod:`repro.core.batched`), the persistent
-:class:`~repro.core.engine.AllocEngine`, and the fleet orchestrator's
-stacked/loop dispatch.
+consumer — the three-phase driver (:mod:`repro.core.batched`), the
+persistent :class:`~repro.core.engine.AllocEngine`, and the fleet
+orchestrator's stacked/loop dispatch.
 
-Layout (the stable facade is this module's namespace; ``repro.core.pdhg``
-re-exports it for backward compatibility):
+Layout (the stable facade is this module's namespace):
 
 * :mod:`~repro.core.solver.options` — :class:`SolverOptions` /
   :class:`SolverState` / :class:`SolveStats`;

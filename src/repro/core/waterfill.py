@@ -11,8 +11,9 @@ devices reaching their own bound included (up to ~1,300 on a 12k hall).
 
 Three fills share these semantics:
 
-* :func:`waterfill_arrays` — the sweep in numpy: the host drivers' fast
-  path and the oracle the tests check the others against;
+* :func:`waterfill_arrays` — the sweep in numpy: the fleet coordinator's
+  host plan (:meth:`repro.fleet.coordinator.BudgetCoordinator.plan`) and
+  the oracle the tests check the others against;
 * :func:`waterfill_jax` — the sweep as a ``lax.while_loop``: the fleet
   coordinator's grant plans (:mod:`repro.fleet.coordinator`,
   :mod:`repro.fleet.sharded`);
@@ -25,8 +26,7 @@ Three fills share these semantics:
 Phase I shares the fills' level-wise search: :func:`tree_project_jax` is the
 weighted least-squares projection of the requests onto the box and the
 caps, Phase I's level QP solved exactly where no tenant rows exist
-(:func:`repro.core.batched.solve_three_phase` and the host
-:func:`repro.core.phases.phase1`).
+(:func:`repro.core.batched.solve_three_phase`).
 """
 
 from __future__ import annotations

@@ -120,9 +120,9 @@ class StepStats(dict):
         ``project_levels_p1``, flags) to host values, fetched from the
         device in one transfer.
 
-        ``scalar=True`` is the engine (K=1) path: leaves become Python
-        ``int``/``bool``/``float`` scalars, matching the pre-PR-8 engine
-        stats dict exactly.
+        ``scalar=True`` is the one-scenario path (the engine's ``step`` and
+        :func:`repro.core.nvpax.optimize`, which strips the lane axis
+        first): leaves become Python ``int``/``bool``/``float`` scalars.
         """
         stats = jax.device_get(dict(stats))
         pi = np.stack(

@@ -1,12 +1,10 @@
 """Equivalence + feasibility of the fully-jitted batched engine
-(:mod:`repro.core.batched`) against the host three-phase path.
+(:mod:`repro.core.batched`) against one lane of it.
 
-The batched engine builds its convex programs through the SAME builders as
-the host driver (``phases.qp_step`` / ``lp_step`` / ``repair`` /
-``saturated_mask``), so per-scenario allocations must match
-``nvpax.optimize`` to solver tolerance — on tree-only problems (waterfill
-fast path) and on tenant-SLA problems (iterated-LP path), with mixed
-priorities and per-scenario activity patterns.
+``nvpax.optimize`` runs the same program on one scenario, so each of K
+vmapped lanes must match it to solver tolerance — on tree-only problems
+(waterfill fast path) and on tenant-SLA problems (iterated-LP path), with
+mixed priorities and per-scenario activity patterns.
 """
 
 from __future__ import annotations
@@ -25,7 +23,7 @@ from repro.core.problem import AllocProblem
 from repro.pdn.tenants import assign_tenants
 from repro.pdn.tree import build_from_level_sizes
 
-# host and batched paths execute structurally identical programs; observed
+# one lane and K lanes execute the same program under vmap; observed
 # deviation is ~1e-13 W.  1e-4 W leaves 9 orders of slack while still
 # asserting "solver tolerance" equality.
 ATOL = 1e-4
@@ -43,7 +41,7 @@ def _tree_feasible(pdn, x, tol=1e-6):
 
 
 def test_batched_matches_sequential_tree_only(pdn):
-    """k = 0: scanned Phase I + jitted waterfill match the host path on
+    """k = 0: scanned Phase I + jitted waterfill match the one-lane path on
     K >= 3 scenarios with differing requests AND activity patterns."""
     rng = np.random.default_rng(0)
     K = 4

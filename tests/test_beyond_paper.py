@@ -10,7 +10,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.core import pdhg, phases
+from repro.core import phases, solver
 from repro.core.nvpax import NvpaxOptions, optimize
 from repro.core.problem import AllocProblem
 from repro.pdn.tree import build_from_level_sizes
@@ -71,8 +71,8 @@ def test_vmap_over_scenarios(pdn):
                 ap0._replace(r=r_vec), ap0.l, ap0.active, jnp.zeros(ap0.n, bool),
                 1e-5, pin_free=True,
             )
-            st = pdhg.SolverState.zeros(ap0.n, tree.m, sla.k, jnp.float64)
-            st, stats = pdhg.solve(prob, tree, sla, st)
+            st = solver.SolverState.zeros(ap0.n, tree.m, sla.k, jnp.float64)
+            st, stats = solver.solve(prob, tree, sla, st)
             return st.x, stats.converged
 
         # NOTE: active masks differ between scenarios; use scenario 0's

@@ -2,11 +2,10 @@
 steps match the rebuild-every-step path, warm-start carry semantics, and the
 batched deadline/iteration-budget mode.
 
-The engine runs the SAME traced program as the batched path
-(``solve_three_phase``) over the same problem builders as the host driver,
-so engine-served steps must match the legacy ``PowerController.step``
-(``AllocProblem.build`` + ``optimize`` every interval) to 1e-9 W — observed
-deviation is ~1e-12.
+The engine runs the SAME traced driver as the batched path and
+``optimize`` (``solve_three_phase``), so engine-served steps must match the
+legacy ``PowerController.step`` (``AllocProblem.build`` + ``optimize``
+every interval) to 1e-9 W — observed deviation is ~1e-12.
 """
 
 from __future__ import annotations
@@ -91,7 +90,7 @@ def test_engine_step_matches_rebuild_path_sla(pdn, sla_fleet):
 def test_engine_pinned_levels_skip_empty(pdn):
     """The engine pins priority levels from the full layout at construction;
     a level with no active devices is skipped by the traced cond, matching
-    the host driver's active-only sweep without recompiling."""
+    ``optimize``'s active-only sweep without recompiling."""
     priority = np.where(np.arange(pdn.n) % 2 == 0, 2, 1).astype(np.int32)
     eng = AllocEngine(pdn, priority=priority)
     assert eng.meta.levels == (2, 1)
@@ -225,7 +224,7 @@ def test_host_warm_carry_reduces_iterations(pdn, sla_fleet):
 
 def test_batched_iter_budget_truncates_to_phase1(pdn, sla_fleet):
     """Budget 1: refinement phases are skipped, allocation == Phase I output
-    (still feasible), stats['truncated'] set — the host path's zero-deadline
+    (still feasible), stats['truncated'] set — a zero deadline's
     semantics."""
     layout, sla = sla_fleet
     rng = np.random.default_rng(7)

@@ -25,7 +25,7 @@ import pytest
 
 from repro.core import engine as engine_mod
 from repro.core.nvpax import NvpaxOptions
-from repro.core.pdhg import SolverOptions
+from repro.core.solver import SolverOptions
 from repro.fleet import FleetLifecycle, FleetOrchestrator
 from repro.fleet import sharded as sharded_mod
 from repro.pdn.hierarchy_gen import homogeneous_fleet

@@ -19,7 +19,7 @@ import pytest
 from repro.core import engine as engine_mod
 from repro.core.engine import AllocEngine
 from repro.core.nvpax import NvpaxOptions
-from repro.core.pdhg import SolverOptions
+from repro.core.solver import SolverOptions
 from repro.fleet import (
     BudgetCoordinator,
     FleetLifecycle,

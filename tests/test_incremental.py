@@ -36,7 +36,7 @@ def small_pdn():
     return build_from_level_sizes([2, 2], gpus_per_server=4, l=200.0, u=700.0)
 
 
-# -- certify tiers (host path) ---------------------------------------------
+# -- certify tiers (one lane) ----------------------------------------------
 
 
 def test_certify_full_skip_on_identical_step():

@@ -92,10 +92,10 @@ def test_dual_prox_sweep(n, dtype, vector_sigma):
 
 
 def test_pdhg_solve_pallas_parity():
-    """pdhg.solve with the fused Pallas update kernels (interpret mode on
+    """solver.solve with the fused Pallas update kernels (interpret mode on
     CPU) matches the pure-jnp inner iteration: same iterate path, same
     iteration count, allocations to solver tolerance."""
-    from repro.core import pdhg
+    from repro.core import solver
     from repro.core.nvpax import NvpaxOptions, optimize
     from repro.core.problem import AllocProblem
     from repro.pdn.tenants import assign_tenants
@@ -105,7 +105,7 @@ def test_pdhg_solve_pallas_parity():
     tele = np.random.default_rng(3).uniform(100, 650, pdn.n)
     ap = AllocProblem.build(pdn, tele, sla=layout.sla_topo(), priority=layout.priority)
     ref = optimize(ap)
-    pal = optimize(ap, NvpaxOptions(solver=pdhg.SolverOptions(use_pallas=True)))
+    pal = optimize(ap, NvpaxOptions(solver=solver.SolverOptions(use_pallas=True)))
     np.testing.assert_allclose(pal.allocation, ref.allocation, atol=1e-9)
     assert pal.stats["total_iterations"] == ref.stats["total_iterations"]
 
@@ -230,13 +230,13 @@ def test_solver_pallas_tree_and_stats_parity():
     """use_pallas_tree / use_pallas_stats route the inner matvecs and the
     chunk-boundary bookkeeping through the kernels without changing the
     solution (iterate paths agree up to reduction association)."""
-    from repro.core import pdhg
+    from repro.core import solver
     from repro.core.nvpax import NvpaxOptions, optimize
 
     ap = _knob_problem()
     ref = optimize(ap)
     for knob in ("use_pallas_tree", "use_pallas_stats"):
-        opts = NvpaxOptions(solver=pdhg.SolverOptions(**{knob: True}))
+        opts = NvpaxOptions(solver=solver.SolverOptions(**{knob: True}))
         got = optimize(ap, opts)
         np.testing.assert_allclose(
             got.allocation, ref.allocation, atol=1e-7, err_msg=knob
@@ -247,14 +247,14 @@ def test_solver_pallas_tree_and_stats_parity():
 def test_solver_blockwise_omega_converges_same_solution():
     """Per-dual-block primal weights change the iterate path but must land
     on the same certified allocation within solve tolerance."""
-    from repro.core import pdhg
+    from repro.core import solver
     from repro.core.nvpax import NvpaxOptions, optimize
 
     ap = _knob_problem()
     tight = dict(eps_abs=1e-9, eps_rel=1e-9)
-    ref = optimize(ap, NvpaxOptions(solver=pdhg.SolverOptions(**tight)))
+    ref = optimize(ap, NvpaxOptions(solver=solver.SolverOptions(**tight)))
     got = optimize(
-        ap, NvpaxOptions(solver=pdhg.SolverOptions(blockwise_omega=True, **tight))
+        ap, NvpaxOptions(solver=solver.SolverOptions(blockwise_omega=True, **tight))
     )
     assert got.stats["converged"]
     np.testing.assert_allclose(got.allocation, ref.allocation, atol=1e-5)

@@ -14,7 +14,8 @@ import pytest
 
 import jax.numpy as jnp
 
-from repro.core import pdhg, phases
+from repro.core import phases, solver
+from repro.core.nvpax import NvpaxOptions, optimize
 from repro.core.problem import AllocProblem
 from repro.core.refsolve import ref_solve
 from repro.pdn.hierarchy_gen import random_hierarchy
@@ -51,8 +52,8 @@ def test_phase1_qp_objective_matches_oracle(seed, with_sla):
     prob = phases.qp_step(
         ap, ap.l, mask_a, jnp.zeros(ap.n, bool), 1e-5, pin_free=not with_sla
     )
-    st = pdhg.SolverState.zeros(ap.n, ap.tree.m, ap.sla.k, jnp.float64)
-    st, stats = pdhg.solve(prob, ap.tree, ap.sla, st)
+    st = solver.SolverState.zeros(ap.n, ap.tree.m, ap.sla.k, jnp.float64)
+    st, stats = solver.solve(prob, ap.tree, ap.sla, st)
     assert bool(stats.converged), "PDHG did not converge"
     zref = ref_solve(prob, ap.tree, ap.sla)
     obj_pdhg = _qp_objective(prob, np.asarray(st.x))
@@ -71,15 +72,16 @@ def test_phase1_qp_objective_matches_oracle(seed, with_sla):
 def test_maxmin_lp_matches_highs(seed):
     """The Phase II LP optimum t* (unique) must match HiGHS."""
     _, ap = _build(seed, with_sla=True)
-    x1, state, _ = phases.phase1(ap, pdhg.SolverOptions())
+    res = optimize(ap, NvpaxOptions(run_phase2=False, run_phase3=False))
+    x1, state = jnp.asarray(res.phase1), res.warm_state.p1
     mask_a = ap.active & ~phases.saturated_mask(x1, ap, ap.active)
     if not bool(np.asarray(mask_a).any()):
         pytest.skip("no unsaturated active devices on this seed")
     prob = phases.lp_step(ap, x1, mask_a, ~(mask_a | ap.idle), ap.idle, 1e-5)
-    st = pdhg.SolverState(
+    st = solver.SolverState(
         x1, jnp.zeros(()), state.y_tree, state.y_sla, state.y_imp
     )
-    st, stats = pdhg.solve(prob, ap.tree, ap.sla, st)
+    st, stats = solver.solve(prob, ap.tree, ap.sla, st)
     assert bool(stats.converged)
     zref = ref_solve(prob, ap.tree, ap.sla)
     t_ref = zref[-1]
@@ -90,13 +92,14 @@ def test_lp_epigraph_bounds_respected(tiny_pdn):
     """Improvement rows a_i - t >= base_i hold at the LP solution."""
     req = np.random.default_rng(3).uniform(100, 700, tiny_pdn.n)
     ap = AllocProblem.build(tiny_pdn, req)
-    x1, state, _ = phases.phase1(ap, pdhg.SolverOptions())
+    res = optimize(ap, NvpaxOptions(run_phase2=False, run_phase3=False))
+    x1, state = jnp.asarray(res.phase1), res.warm_state.p1
     mask_a = ap.active & ~phases.saturated_mask(x1, ap, ap.active)
     if not bool(np.asarray(mask_a).any()):
         pytest.skip("all saturated")
     prob = phases.lp_step(ap, x1, mask_a, ~(mask_a | ap.idle), ap.idle, 1e-5)
-    st = pdhg.SolverState(x1, jnp.zeros(()), state.y_tree, state.y_sla, state.y_imp)
-    st, stats = pdhg.solve(prob, ap.tree, ap.sla, st)
+    st = solver.SolverState(x1, jnp.zeros(()), state.y_tree, state.y_sla, state.y_imp)
+    st, stats = solver.solve(prob, ap.tree, ap.sla, st)
     x, t = np.asarray(st.x), float(st.t)
     sel = np.asarray(mask_a)
     assert (x[sel] - np.asarray(x1)[sel] >= t - 1e-4).all()
@@ -113,15 +116,15 @@ def test_warm_start_reduces_iterations(small_pdn):
     prob = phases.qp_step(
         ap, ap.l, ap.active, jnp.zeros(ap.n, bool), 1e-5, pin_free=True
     )
-    cold = pdhg.SolverState.zeros(ap.n, ap.tree.m, ap.sla.k, jnp.float64)
-    st, stats_cold = pdhg.solve(prob, ap.tree, ap.sla, cold)
+    cold = solver.SolverState.zeros(ap.n, ap.tree.m, ap.sla.k, jnp.float64)
+    st, stats_cold = solver.solve(prob, ap.tree, ap.sla, cold)
     # perturb requests slightly (next control step)
     req2 = req + rng.normal(0, 5.0, small_pdn.n)
     ap2 = AllocProblem.build(small_pdn, req2)
     prob2 = phases.qp_step(
         ap2, ap2.l, ap2.active, jnp.zeros(ap2.n, bool), 1e-5, pin_free=True
     )
-    st2, stats_warm = pdhg.solve(prob2, ap2.tree, ap2.sla, st)
+    st2, stats_warm = solver.solve(prob2, ap2.tree, ap2.sla, st)
     assert bool(stats_warm.converged)
     assert int(stats_warm.iterations) <= int(stats_cold.iterations)
 
@@ -133,7 +136,7 @@ def test_pinned_variables_stay_pinned(tiny_pdn):
     pin_val = jnp.full((8,), 333.0)
     mask_a = ap.active & ~pin_mask
     prob = phases.qp_step(ap, pin_val, mask_a, pin_mask, 1e-5, pin_free=True)
-    st = pdhg.SolverState.zeros(ap.n, ap.tree.m, ap.sla.k, jnp.float64)
-    st, stats = pdhg.solve(prob, ap.tree, ap.sla, st)
+    st = solver.SolverState.zeros(ap.n, ap.tree.m, ap.sla.k, jnp.float64)
+    st, stats = solver.solve(prob, ap.tree, ap.sla, st)
     x = np.asarray(st.x)
     np.testing.assert_allclose(x[[0, 4]], 333.0, atol=1e-6)

@@ -12,7 +12,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.core import pdhg, phases
+from repro.core import phases, solver
 from repro.core.nvpax import NvpaxOptions, optimize
 from repro.core.problem import AllocProblem
 from repro.core.treeops import TreeTopo
@@ -132,9 +132,9 @@ def test_phase1_processes_priorities_high_to_low():
     req = np.full(8, 600.0)
     prio = np.array([1, 1, 2, 2, 3, 3, 1, 1], np.int32)
     ap = AllocProblem.build(pdn, req, active=np.ones(8, bool), priority=prio)
-    x, state, stats = phases.phase1(ap, pdhg.SolverOptions())
-    assert stats.solves == 3  # one QP per distinct priority level
-    assert stats.converged
+    res = optimize(ap, NvpaxOptions(run_phase2=False, run_phase3=False))
+    assert res.stats["solves"] == 3  # one QP per distinct priority level
+    assert res.stats["converged"]
 
 
 def test_maxmin_phase_invariant_opt_plus_fixed():
@@ -142,12 +142,9 @@ def test_maxmin_phase_invariant_opt_plus_fixed():
     pdn = build_from_level_sizes([2, 2], gpus_per_server=4)
     req = np.full(pdn.n, 300.0)
     ap = AllocProblem.build(pdn, req, active=np.ones(pdn.n, bool))
-    x1, state, _ = phases.phase1(ap, pdhg.SolverOptions())
-    x2, _, st2 = phases.run_maxmin_phase(
-        ap, x1, ap.active, ap.idle, pdhg.SolverOptions(), use_waterfill=False
-    )
-    assert st2.converged
-    assert (np.asarray(x2) >= np.asarray(x1) - 1e-9).all()
+    res = optimize(ap, NvpaxOptions(use_waterfill=False, run_phase3=False))
+    assert res.stats["converged"]
+    assert (res.phase2 >= res.phase1 - 1e-9).all()
 
 
 def _two_rack_tree():
@@ -474,7 +471,7 @@ def _phase1_reference(ap, pin_free):
 )
 def test_phase1_projection_matches_the_reference(case):
     """On problems with no tenant rows, Phase I through the jitted scan and
-    through the host driver is the exact projection: each equals
+    through ``optimize`` is the exact projection: each equals
     reference's level QPs to 1e-6 W (1e-4 W in float32), runs no PDHG
     iteration, and searches exactly where a node binds."""
     from repro.core.batched import _phase1_scan, batch_meta, stack_problems
@@ -492,7 +489,7 @@ def test_phase1_projection_matches_the_reference(case):
             l=l, u=u, r=r, priority=priority, active=active, weight_scale=weight_scale
         )
         warm = SolverState.zeros(n, m, 0, dtype)
-        st = _phase1_scan(ap, meta, pdhg.SolverOptions(), warm)
+        st = _phase1_scan(ap, meta, solver.SolverOptions(), warm)
         return st.x, st.iterations, st.search_steps, st.search_levels
 
     lanes = (stacked.l, stacked.u, stacked.r, stacked.priority, stacked.active,
@@ -505,9 +502,10 @@ def test_phase1_projection_matches_the_reference(case):
         np.testing.assert_allclose(x, want, rtol=0, atol=atol)
         np.testing.assert_array_equal(x[held], np.asarray(ap.l)[held])
         if pin_free:
-            x_host, _, st = phases.phase1(ap, pdhg.SolverOptions())
-            np.testing.assert_allclose(np.asarray(x_host), x, rtol=0, atol=1e-9)
-            assert st.iterations == 0 and st.solves == len(ap.priority_levels())
+            one = optimize(ap, NvpaxOptions(run_phase2=False, run_phase3=False))
+            np.testing.assert_allclose(one.phase1, x, rtol=0, atol=1e-9)
+            assert one.stats["phase_iterations"][0] == 0
+            assert one.stats["solves"] == len(ap.priority_levels())
         # a level is searched exactly where its nodes bind at the requests
         x0 = np.clip(np.asarray(ap.r), np.asarray(ap.l), np.asarray(ap.u))
         x0 = np.where(np.asarray(ap.active), x0, np.asarray(ap.l))
@@ -571,6 +569,6 @@ def test_phase1_keeps_pdhg_on_problems_with_tenant_rows():
         atol=1e-6,
     )
     assert (res.stats["project_steps_p1"] == 0).all()
-    x1, _, st = phases.phase1(aps[0], pdhg.SolverOptions())
-    assert st.iterations == 200
-    np.testing.assert_allclose(np.asarray(x1), res.phase1[0], rtol=0, atol=1e-9)
+    one = optimize(aps[0], NvpaxOptions(run_phase2=False, run_phase3=False))
+    assert one.stats["phase_iterations"][0] == 200
+    np.testing.assert_allclose(one.phase1, res.phase1[0], rtol=0, atol=1e-9)

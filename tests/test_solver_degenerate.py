@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from repro.core import phases, solver
 from repro.core.batched import optimize_batched
 from repro.core.engine import AllocEngine, trace_count
-from repro.core.nvpax import optimize
+from repro.core.nvpax import NvpaxOptions, optimize
 from repro.core.problem import AllocProblem
 from repro.pdn.tenants import assign_tenants
 from repro.pdn.tree import build_from_level_sizes
@@ -57,8 +57,9 @@ def degenerate_problem(seed=0, ties=False):
 
 def degenerate_lp(ap):
     """The Phase II max-min LP after a converged Phase I."""
-    x1, state, s1 = phases.phase1(ap, solver.SolverOptions())
-    assert s1.converged
+    res = optimize(ap, NvpaxOptions(run_phase2=False, run_phase3=False))
+    assert res.stats["converged"]
+    x1, state = jnp.asarray(res.phase1), res.warm_state.p1
     mask_a = ap.active & ~phases.saturated_mask(x1, ap, ap.active)
     assert bool(np.asarray(mask_a).any())
     prob = phases.lp_step(ap, x1, mask_a, ~(mask_a | ap.idle), ap.idle, 1e-5)
